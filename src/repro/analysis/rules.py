@@ -88,16 +88,21 @@ def _functions(ctx: ModuleContext):
 
 
 def _is_jax_jit(call: ast.Call) -> bool:
+    """``jax.jit(...)``, or ``repro.runtime.donating_jit(fun, argnums)``."""
     chain = attr_chain(call.func)
-    return chain[-2:] == ["jax", "jit"] or chain == ["jit"]
+    return chain[-2:] == ["jax", "jit"] or chain in (["jit"],
+                                                     ["donating_jit"])
 
 
 def _donated_positions(call: ast.Call, ctx: ModuleContext):
-    """Parse ``donate_argnums=`` from a jax.jit call: a constant tuple, an
+    """Parse ``donate_argnums`` from a jit call: a constant tuple, an
     IfExp over tuples (the repo's ``(0,) if tpu else ()`` idiom), or a Name
     bound to either nearby. Returns a set of positions, or None (no
     donation), or 'all' when unparseable (conservative)."""
-    kw = next((k for k in call.keywords if k.arg == "donate_argnums"), None)
+    kw = next((k.value for k in call.keywords if k.arg == "donate_argnums"),
+              None)
+    if kw is None and call_name(call) == "donating_jit" and len(call.args) > 1:
+        kw = call.args[1]
     if kw is None:
         return None
 
@@ -125,7 +130,7 @@ def _donated_positions(call: ast.Call, ctx: ModuleContext):
             return None
         return None
 
-    got = positions(kw.value)
+    got = positions(kw)
     return got if got is not None else "all"
 
 
@@ -171,7 +176,7 @@ class DonationAfterUse(Rule):
 
     def _check_fn(self, ctx, fn, donators):
         # vars holding donation-aware pool state (decode_state /
-        # make_decode_cache hand out buffers that a donating step consumes)
+        # pool_state hand out buffers that a donating step consumes)
         handles: set[str] = set()
         donated: dict[str, ast.Call] = {}     # var -> donating call
         exempt: set[int] = set()              # Name node ids at donation site
@@ -200,7 +205,7 @@ class DonationAfterUse(Rule):
                             handles.discard(t.id)
                 if (isinstance(node.value, ast.Call)
                         and call_name(node.value) in ("decode_state",
-                                                      "make_decode_cache")
+                                                      "pool_state")
                         and len(node.targets) == 1
                         and isinstance(node.targets[0], ast.Name)):
                     handles.add(node.targets[0].id)

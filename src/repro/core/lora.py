@@ -21,8 +21,8 @@ Serving has two ways to consume adapters:
     legacy per-model decode path);
   - the fused decode plane stacks just the (tiny) A/B factors
     (``stack_lora_params``) and performs the same merge INSIDE its jitted
-    vmapped step (serving/decode.py), so N adapter-factored decode modules
-    store one base copy + N adapter sets instead of N full models.
+    step (serving/decode.py), so N adapter-factored decode modules store
+    one base copy + N adapter sets instead of N full models.
 """
 from __future__ import annotations
 
@@ -111,23 +111,13 @@ def lora_param_count(lora_params) -> int:
     return sum(x.size for x in jax.tree.leaves(lora_params))
 
 
-def stack_params(param_list):
-    """Stack structurally-identical param pytrees on a NEW leading model axis.
-
-    This is the fused decode plane's parameter layout: N task-specific decode
-    modules sharing one ModelConfig become one pytree whose every leaf is
-    (N, ...), so a single vmapped forward advances sequences of all N models
-    in one dispatch (serving.decode.StackedDecoders)."""
-    assert param_list, "need at least one param pytree to stack"
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *param_list)
-
-
 def stack_lora_params(lora_list):
-    """``stack_params`` for LoRA adapter pytrees (None where untargeted).
+    """Stack LoRA adapter pytrees (None where untargeted) on a NEW leading
+    model axis.
 
-    Memory-lean variant of the fused plane for adapter-only decoders: stack
-    just the (tiny) A/B factors and merge ``W + scale * A[m] @ B[m]`` inside
-    the vmapped step, instead of stacking N full materialized models. None
+    The fused plane's layout for adapter-only decoders: stack just the
+    (tiny) A/B factors and merge ``W + scale * A[m] @ B[m]`` inside the
+    jitted step, instead of holding N full materialized models. None
     positions (untargeted weights) are empty pytree nodes and survive as-is;
     adapter-target mismatches between the stacked models surface as a tree
     structure error."""

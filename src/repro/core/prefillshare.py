@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import encode, forward, init_cache
 from repro.models.model import train_loss as _plain_train_loss
+from repro.runtime import donating_jit
 
 Params = Any
 Cache = Any
@@ -96,6 +97,29 @@ def base_prefill_paged(cfg: ModelConfig, base_params: Params, new_tokens, *,
     return out
 
 
+def paged_forward(cfg: ModelConfig, params, tokens, state, block_tables, pos,
+                  **kw):
+    """``forward`` over the paged pool (traceable: call it inside a jitted
+    step). ``state`` is a ``decode_state`` pytree, ``block_tables`` (B,
+    npages) int32 and ``pos`` (B,) the absolute position of ``tokens[:, 0]``.
+    Each layer appends the tokens' K/V rows to their pages and attends
+    straight from the pool. Returns (output, new_state)."""
+    bt = jnp.asarray(block_tables, jnp.int32)
+
+    def wire(st):
+        return {"k_pages": st["k"], "v_pages": st["v"], "block_tables": bt}
+
+    cache = {"groups": {g: wire(st) for g, st in state["groups"].items()},
+             "tail": [wire(st) for st in state["tail"]]}
+    out, cache, _ = forward(cfg, params, tokens, cache=cache, pos=pos, **kw)
+
+    def unwire(c):
+        return {"k": c["k_pages"], "v": c["v_pages"]}
+
+    return out, {"groups": {g: unwire(c) for g, c in cache["groups"].items()},
+                 "tail": [unwire(c) for c in cache["tail"]]}
+
+
 _CHUNK_STEPS: dict = {}
 #: retrace counter per config (the trace-scaling tests read this): the jitted
 #: chunk step retraces per distinct (B, S, npages) shape — with the
@@ -105,12 +129,14 @@ CHUNK_TRACES: dict = {}
 
 
 def _make_chunk_step(cfg: ModelConfig):
-    def _step(params, toks, pos, cache):
+    def _step(params, toks, pos, state, block_tables):
         CHUNK_TRACES[cfg] = CHUNK_TRACES.get(cfg, 0) + 1   # once per trace
-        _, new_cache, _ = forward(cfg, params, toks, cache=cache, pos=pos,
-                                  logits="hidden")
-        return new_cache
-    return jax.jit(_step)
+        _, new_state = paged_forward(cfg, params, toks, state, block_tables,
+                                     pos, logits="hidden")
+        return new_state
+    # the pool (arg 3) is donated on TPU: each chunk appends its rows in
+    # place instead of allocating a second pool
+    return donating_jit(_step, (3,))
 
 
 def base_prefill_chunk(cfg: ModelConfig, base_params: Params, tokens, *,
@@ -133,12 +159,12 @@ def base_prefill_chunk(cfg: ModelConfig, base_params: Params, tokens, *,
     """
     if cfg not in _CHUNK_STEPS:
         _CHUNK_STEPS[cfg] = _make_chunk_step(cfg)
-    step = _CHUNK_STEPS[cfg]
-    cache = pool.make_decode_cache(jnp.asarray(block_tables, jnp.int32))
-    new_cache = step(base_params, jnp.asarray(tokens, jnp.int32),
-                     jnp.asarray(pos, jnp.int32), cache)
-    pool.absorb_decode_cache(new_cache)
-    return new_cache
+    new_state = _CHUNK_STEPS[cfg](
+        base_params, jnp.asarray(tokens, jnp.int32),
+        jnp.asarray(pos, jnp.int32), pool.decode_state(),
+        jnp.asarray(block_tables, jnp.int32))
+    pool.absorb_decode_state(new_state)
+    return new_state
 
 
 # ======================================================================

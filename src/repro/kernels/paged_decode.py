@@ -1,16 +1,24 @@
 """Pallas TPU kernel: paged decode attention over the shared KV pool.
 
-TPU-native adaptation of vLLM's PagedAttention (DESIGN.md §3): the decode
-worker's KV lives in a paged pool; each sequence owns a block table mapping
-logical pages -> physical pages. PrefillShare hands off *base-model* pages to
-every decode worker, so the pool layout is the cross-model-shared artifact.
+TPU-native adaptation of vLLM's PagedAttention: the decode worker's KV lives
+in a paged pool; each sequence owns a block table mapping logical pages ->
+physical pages. PrefillShare hands off *base-model* pages to every decode
+worker, so the pool layout is the cross-model-shared artifact.
 
-The block table + sequence lengths ride in scalar-prefetch (SMEM) via
-``PrefetchScalarGridSpec``, so the K/V BlockSpec index maps dereference the
-page table while the previous page streams HBM->VMEM. Grid iterates
-(batch, kv_head, page); the full GQA query group for a kv head is processed
-together (q block (group, D)), amortizing each K/V page fetch across the
-group — the same trick the prefill kernel uses.
+Pool layout: ``(P, page, Hkv * D)`` — the heads of one token row are
+contiguous — optionally stacked on a leading layer axis ``(L, P, page,
+Hkv * D)`` and read at ``layer``, so a layer-scanned model step hands the
+kernel the whole stacked pool instead of slicing (copying) one layer out of
+it. One kv head's rows of one page are the ``(page, D)`` block at lane
+offset ``h * D``: a legal TPU block (``page`` a multiple of 8, ``D`` of 128)
+that a strided DMA fetches straight from HBM.
+
+The layer index, block table and sequence lengths ride in scalar-prefetch
+(SMEM) via ``PrefetchScalarGridSpec``, so the K/V BlockSpec index maps
+dereference the page table while the previous page streams HBM->VMEM. Grid
+iterates (batch, kv_head, page); the full GQA query group for a kv head is
+processed together (q block (group, D)), amortizing each K/V page fetch
+across the group.
 """
 from __future__ import annotations
 
@@ -21,14 +29,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 NEG = -1e30
 
 
-def _kernel(block_tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
-            m_scr, l_scr, acc_scr, *, scale: float, softcap: float,
+def _kernel(layer_ref, block_tables_ref, lengths_ref, q_ref, k_ref, v_ref,
+            o_ref, m_scr, l_scr, acc_scr, *, scale: float, softcap: float,
             page: int, npages: int, group: int):
+    del layer_ref, block_tables_ref         # consumed by the index maps
     b = pl.program_id(0)
     j = pl.program_id(2)
 
@@ -43,8 +50,8 @@ def _kernel(block_tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale    # (group, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (page, D)
+        q = q_ref[0, 0].astype(jnp.float32) * scale          # (group, D)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (page, D)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if softcap:
@@ -57,7 +64,7 @@ def _kernel(block_tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(s - m_new) * mask
         corr = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
         acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -65,49 +72,61 @@ def _kernel(block_tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j == npages - 1)
     def _final():
-        o_ref[0, :, 0, :] = (acc_scr[...] /
-                             jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] /
+                       jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+
+
+def as_layer_stack(k_pages, v_pages, layer):
+    """Normalize a pool to the kernels' layer-stacked form: a single layer's
+    ``(P, page, F)`` pool becomes a one-layer stack read at layer 0; the
+    layer index becomes the ``(1,)`` int32 array scalar prefetch takes."""
+    if k_pages.ndim == 3:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    return k_pages, v_pages, jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
-                           softcap: float = 0.0, scale: float | None = None,
+                           layer=None, softcap: float = 0.0,
+                           scale: float | None = None,
                            interpret: bool = False):
     """Single-token decode attention over a paged KV pool.
 
     q:            (B, Hq, D) current-step queries
-    k_pages:      (P, page_size, Hkv, D) physical key pool
-    v_pages:      (P, page_size, Hkv, D) physical value pool
+    k_pages:      (P, page_size, Hkv * D) physical key pool, or the
+                  layer-stacked (L, P, page_size, Hkv * D) pool
+    v_pages:      same layout as ``k_pages``
     block_tables: (B, npages) int32 logical->physical page ids
     lengths:      (B,) int32 valid KV length per sequence
+    layer:        int32 scalar: the layer a stacked pool is read at
     returns       (B, Hq, D)
     """
     B, Hq, D = q.shape
-    P, page, Hkv, _ = k_pages.shape
+    k_pages, v_pages, layer = as_layer_stack(k_pages, v_pages, layer)
+    page = k_pages.shape[2]
+    Hkv = k_pages.shape[3] // D
     npages = block_tables.shape[1]
     group = Hq // Hkv
     scale = D ** -0.5 if scale is None else scale
 
-    # (B, Hkv, group, D): query group per kv head
-    qg = q.reshape(B, Hkv, group, D)
-
     kernel = functools.partial(_kernel, scale=scale, softcap=softcap,
                                page=page, npages=npages, group=group)
 
+    def q_map(b, h, j, ly, bt, ln):
+        return (b, h, 0, 0)
+
+    def page_map(b, h, j, ly, bt, ln):
+        return (ly[0], bt[b, j], 0, h)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, Hkv, npages),
         in_specs=[
-            # q: whole group for (b, h)
-            pl.BlockSpec((1, group, 1, D),
-                         lambda b, h, j, bt, ln: (b, 0, h, 0)),
-            # k/v page: physical page id from the prefetched block table
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, j, bt, ln: (bt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, j, bt, ln: (bt[b, j], 0, h, 0)),
+            pl.BlockSpec((1, 1, group, D), q_map),   # whole group for (b, h)
+            # head h's rows of the page the block table names
+            pl.BlockSpec((1, 1, page, D), page_map),
+            pl.BlockSpec((1, 1, page, D), page_map),
         ],
-        out_specs=pl.BlockSpec((1, group, 1, D),
-                               lambda b, h, j, bt, ln: (b, 0, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, group, D), q_map),
         scratch_shapes=[
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
@@ -118,9 +137,11 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, group, Hkv, D), q.dtype),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables, lengths, qg.transpose(0, 2, 1, 3), k_pages, v_pages)
-    return out.transpose(0, 2, 1, 3).reshape(B, Hq, D)
+        name="paged_decode",
+    )(layer, block_tables, lengths, q.reshape(B, Hkv, group, D),
+      k_pages, v_pages)
+    return out.reshape(B, Hq, D)

@@ -20,8 +20,6 @@ import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _kernel(tables_ref, nvalid_ref, new_k_ref, new_v_ref, kpool_ref,
             vpool_ref, kout_ref, vout_ref, *, page: int):
@@ -44,14 +42,15 @@ def paged_write(new_k, new_v, k_pages, v_pages, block_tables, n_valid, *,
                 interpret: bool = False):
     """Write per-request new KV rows into their assigned physical pages.
 
-    new_k/new_v:  (B, S, Hkv, D) freshly computed KV (S = n_pages * page)
-    k/v_pages:    (P, page, Hkv, D) physical pools (updated in place)
+    new_k/new_v:  (B, S, F) freshly computed KV rows, F = Hkv * D
+                  (S = n_pages * page)
+    k/v_pages:    (P, page, F) physical pools (updated in place)
     block_tables: (B, npages) int32 physical page per logical page
     n_valid:      (B,) int32 number of valid pages per request
     returns updated (k_pages, v_pages)
     """
-    B, S, Hkv, D = new_k.shape
-    P, page = k_pages.shape[0], k_pages.shape[1]
+    B, S, F = new_k.shape
+    page = k_pages.shape[1]
     npages = S // page
     assert npages == block_tables.shape[1], (npages, block_tables.shape)
 
@@ -61,18 +60,18 @@ def paged_write(new_k, new_v, k_pages, v_pages, block_tables, n_valid, *,
         num_scalar_prefetch=2,
         grid=(B, npages),
         in_specs=[
-            pl.BlockSpec((1, page, Hkv, D), lambda b, j, bt, nv: (b, j, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, D), lambda b, j, bt, nv: (b, j, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, D),
-                         lambda b, j, bt, nv: (bt[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, D),
-                         lambda b, j, bt, nv: (bt[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, page, F), lambda b, j, bt, nv: (b, j, 0)),
+            pl.BlockSpec((1, page, F), lambda b, j, bt, nv: (b, j, 0)),
+            pl.BlockSpec((1, page, F),
+                         lambda b, j, bt, nv: (bt[b, j], 0, 0)),
+            pl.BlockSpec((1, page, F),
+                         lambda b, j, bt, nv: (bt[b, j], 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, page, Hkv, D),
-                         lambda b, j, bt, nv: (bt[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, D),
-                         lambda b, j, bt, nv: (bt[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, page, F),
+                         lambda b, j, bt, nv: (bt[b, j], 0, 0)),
+            pl.BlockSpec((1, page, F),
+                         lambda b, j, bt, nv: (bt[b, j], 0, 0)),
         ],
     )
 
@@ -84,7 +83,8 @@ def paged_write(new_k, new_v, k_pages, v_pages, block_tables, n_valid, *,
         input_output_aliases={4: 0, 5: 1},   # pools updated in place
         # grid points may alias pool revisions (bt is data-dependent): keep
         # the page axis sequential; requests are independent.
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_write",
     )(block_tables, n_valid, new_k, new_v, k_pages, v_pages)

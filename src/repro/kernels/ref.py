@@ -31,20 +31,26 @@ def ref_flash_prefill(q, k, v, *, causal: bool = True, window: int = 0,
     return o.reshape(B, Hq, S, D).astype(q.dtype)
 
 
+def _gather_pages(k_pages, v_pages, block_tables, layer, D):
+    """Pool pages named by ``block_tables`` as contiguous (B, T, Hkv, D) K/V.
+    Pools are (P, page, Hkv * D), or layer-stacked (L, P, page, Hkv * D)
+    and read at ``layer`` (the kernels' contract)."""
+    idx = block_tables if k_pages.ndim == 3 else (layer, block_tables)
+    B, npages = block_tables.shape
+    page, F = k_pages.shape[-2], k_pages.shape[-1]
+    shape = (B, npages * page, F // D, D)
+    return k_pages[idx].reshape(shape), v_pages[idx].reshape(shape)
+
+
 def ref_paged_decode(q, k_pages, v_pages, block_tables, lengths, *,
-                     softcap: float = 0.0, scale: float | None = None):
+                     layer=None, softcap: float = 0.0,
+                     scale: float | None = None):
     """Gather pages into contiguous KV, then masked softmax attention."""
     B, Hq, D = q.shape
-    P, page, Hkv, _ = k_pages.shape
-    npages = block_tables.shape[1]
+    k, v = _gather_pages(k_pages, v_pages, block_tables, layer, D)
+    T, Hkv = k.shape[1], k.shape[2]
     g = Hq // Hkv
     scale = D ** -0.5 if scale is None else scale
-
-    k = k_pages[block_tables]            # (B, npages, page, Hkv, D)
-    v = v_pages[block_tables]
-    T = npages * page
-    k = k.reshape(B, T, Hkv, D)
-    v = v.reshape(B, T, Hkv, D)
 
     qg = q.reshape(B, Hkv, g, D).astype(jnp.float32) * scale
     s = jnp.einsum("bhgd,bthd->bhgt", qg, k.astype(jnp.float32))
@@ -58,20 +64,17 @@ def ref_paged_decode(q, k_pages, v_pages, block_tables, lengths, *,
 
 
 def ref_paged_prefill(q, k_pages, v_pages, block_tables, start, *,
-                      softcap: float = 0.0, scale: float | None = None):
+                      layer=None, softcap: float = 0.0,
+                      scale: float | None = None):
     """Chunk-prefill attention over pages: gather the block table into
     contiguous KV, then a materialized causal softmax at absolute positions
     (q[b, i] sits at ``start[b] + i``). Mirrors ``_direct``'s op ordering so
     chunked and dense prefill agree token-for-token."""
     B, S, Hq, D = q.shape
-    P, page, Hkv, _ = k_pages.shape
-    npages = block_tables.shape[1]
+    k, v = _gather_pages(k_pages, v_pages, block_tables, layer, D)
+    T, Hkv = k.shape[1], k.shape[2]
     g = Hq // Hkv
-    T = npages * page
     scale = D ** -0.5 if scale is None else scale
-
-    k = k_pages[block_tables].reshape(B, T, Hkv, D)
-    v = v_pages[block_tables].reshape(B, T, Hkv, D)
 
     qg = (q.astype(jnp.float32) * scale).reshape(B, S, Hkv, g, D)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(jnp.float32))
@@ -89,13 +92,13 @@ def ref_paged_prefill(q, k_pages, v_pages, block_tables, start, *,
 def ref_paged_write(new_k, new_v, k_pages, v_pages, block_tables, n_valid):
     """Scatter new KV rows into assigned pages (numpy-style oracle)."""
     import numpy as np
-    B, S, Hkv, D = new_k.shape
+    B, S, F = new_k.shape
     page = k_pages.shape[1]
     npages = S // page
     ko = np.array(k_pages)
     vo = np.array(v_pages)
-    nk = np.array(new_k).reshape(B, npages, page, Hkv, D)
-    nv = np.array(new_v).reshape(B, npages, page, Hkv, D)
+    nk = np.array(new_k).reshape(B, npages, page, F)
+    nv = np.array(new_v).reshape(B, npages, page, F)
     bt = np.array(block_tables)
     for b in range(B):
         for j in range(int(n_valid[b])):

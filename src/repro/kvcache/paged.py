@@ -6,30 +6,36 @@ automatic-prefix-caching PR, ONE engine-global radix tree is the single
 source of prefix truth over these pages: a page published by any prefill
 worker is matchable by every other). This module gives
 those ids physical storage: per-layer K/V page arrays shaped
-``(P, page_size, Hkv, head_dim)`` (stacked over the model's scanned layer
+``(P, page_size, Hkv * head_dim)`` (stacked over the model's scanned layer
 groups), so a block id allocated by any prefill worker addresses real tensors
 readable by every decode worker. That is the zero-copy handoff invariant of
 the shared-prefill design: handing a request to a decode model moves a block
 table (a few bytes of page ids), never the KV itself.
+
+A token row keeps its heads contiguous (``Hkv * head_dim``, the dense caches'
+flattened feature layout): one kv head of one page is then the ``(page,
+head_dim)`` block at lane offset ``h * head_dim`` — a block the TPU's
+compiler accepts and a strided DMA fetches without any relayout of the pool.
 
 Data flow:
   - prefill: ``gather_prefill_cache`` materializes the cached prefix as a
     dense working cache (the compute plane for incremental attention), the
     frozen base model extends it, and ``scatter_from_dense`` writes the fresh
     page-aligned rows back into the pool via the ``paged_write`` Pallas
-    kernel (interpret mode off-TPU).
-  - decode: ``make_decode_cache`` wires the pool arrays + per-sequence block
-    tables into the model cache pytree; ``repro.models.attention`` then runs
-    the paged decode-attention step (Pallas kernel on TPU, jnp gather twin
-    elsewhere) and appends each generated token's KV to the sequence's
-    private tail page; ``absorb_decode_cache`` publishes the updated pages.
+    kernel (interpret mode off-TPU) in one jitted, donated launch.
+  - chunked prefill and decode: ``core.prefillshare.paged_forward`` wires
+    the ``decode_state`` arrays + per-sequence block tables into the model
+    cache pytree; ``repro.models.model.forward`` carries the pool through
+    its layer scan, each layer appending its rows in place and attending
+    straight from the pages (Pallas kernel on TPU, jnp gather twin
+    elsewhere).
 
 Donation-aware decode state: ``decode_state()`` hands the page buffers out as
-one pytree to be passed INTO a jitted decode step (donated on TPU, so XLA
-updates the touched pages in place instead of functionally copying the pool
-per step — mirroring ``copy_page``), and ``absorb_decode_state`` stores the
-step's returned buffers back. Off-TPU donation is a no-op and the pair
-degrades to the plain functional update.
+one pytree to be passed INTO a jitted step (donated on TPU, so XLA updates
+the touched pages in place instead of functionally copying the pool per
+step — mirroring ``copy_page``), and ``absorb_decode_state`` stores the
+step's returned buffers back. Off-TPU donation is off and the pair degrades
+to the plain functional update.
 
 Physical row 0 is the padding sentinel (``BlockPool.SENTINEL``): it is never
 allocated, so ragged block tables zero-padded to a common width can never
@@ -43,17 +49,14 @@ import jax.numpy as jnp
 
 from repro.configs.base import ATTN, ModelConfig
 from repro.kernels.paged_write import paged_write
-
-
-def _interp(interpret):
-    return (jax.default_backend() != "tpu") if interpret is None else interpret
+from repro.runtime import donating_jit, on_tpu
 
 
 def _copy_page_impl(state, src, dst):
-    # group arrays are (n_full, P, page, Hkv, D): page axis 1; tail arrays
-    # are (P, page, Hkv, D): page axis 0.
+    # group arrays are (n_full, P, page, F): page axis 1; tail arrays are
+    # (P, page, F): page axis 0.
     def cp(a):
-        if a.ndim == 5:
+        if a.ndim == 4:
             return a.at[:, dst].set(a[:, src])
         return a.at[dst].set(a[src])
     return jax.tree.map(cp, state)
@@ -61,11 +64,41 @@ def _copy_page_impl(state, src, dst):
 
 # One fused, jitted update over the whole pool pytree; donating the pool
 # buffers lets XLA update the touched pages in place instead of copying the
-# pool per clone (donation is a no-op on backends that ignore it, so only
-# request it where it's honoured).
-_copy_page_jit = jax.jit(
-    _copy_page_impl,
-    donate_argnums=(0,) if jax.default_backend() == "tpu" else ())
+# pool per clone.
+_copy_page_jit = donating_jit(_copy_page_impl, (0,))
+
+
+def _write_pages_impl(state, rows, block_table):
+    """``paged_write`` of page-aligned dense rows into every layer's pool.
+    ``rows`` mirrors ``state``: (n_full, span, F) per group, (span, F) per
+    tail layer; ``block_table`` (npages,) names the destination pages."""
+    interp = not on_tpu()
+    n_pages = block_table.shape[0]
+    nvalid = jnp.full((1,), n_pages, jnp.int32)
+    new = {"groups": {}, "tail": []}
+    for g, st in state["groups"].items():
+        # ONE kernel launch per group: the stacked (n_full, P, page, F) layer
+        # axis folds into paged_write's batch axis by flattening the pool to
+        # (n_full * P, page, F) and offsetting each layer's block table by
+        # its pool stride.
+        n_full, P = st["k"].shape[:2]
+        off = (jnp.arange(n_full, dtype=jnp.int32) * P)[:, None]
+        bt_l = block_table[None] + off                        # (n_full, npages)
+        nv_l = jnp.broadcast_to(nvalid, (n_full,))
+        flat = (n_full * P,) + st["k"].shape[2:]
+        kp, vp = paged_write(rows["groups"][g]["k"], rows["groups"][g]["v"],
+                             st["k"].reshape(flat), st["v"].reshape(flat),
+                             bt_l, nv_l, interpret=interp)
+        new["groups"][g] = {"k": kp.reshape(st["k"].shape),
+                            "v": vp.reshape(st["v"].shape)}
+    for st, rw in zip(state["tail"], rows["tail"]):
+        kp, vp = paged_write(rw["k"][None], rw["v"][None], st["k"], st["v"],
+                             block_table[None], nvalid, interpret=interp)
+        new["tail"].append({"k": kp, "v": vp})
+    return new
+
+
+_write_pages_jit = donating_jit(_write_pages_impl, (0,))
 
 
 class PagedKVPool:
@@ -92,7 +125,7 @@ class PagedKVPool:
         n_tail = cfg.n_layers % len(pat)
 
         # +1 physical row: row 0 is the never-allocated padding sentinel
-        shape = (num_pages + 1, page_size, self.hkv, self.hd)
+        shape = (num_pages + 1, page_size, self.hkv * self.hd)
         self.k_groups = {f"pos{i}": jnp.zeros((self.n_full,) + shape, dt)
                          for i in range(len(pat))} if self.n_full else {}
         self.v_groups = {g: jnp.zeros_like(a) for g, a in self.k_groups.items()}
@@ -137,9 +170,10 @@ class PagedKVPool:
         return {"groups": groups, "tail": tail}
 
     def scatter_from_dense(self, cache, block_table, start_page: int,
-                           n_new_pages: int, *, interpret=None):
+                           n_new_pages: int):
         """Write pages ``[start_page, start_page + n_new_pages)`` of a dense
-        B=1 working cache into their physical pool pages (paged_write kernel).
+        B=1 working cache into their physical pool pages (paged_write kernel,
+        one jitted launch with the pool donated on TPU).
 
         Rows are taken from the *updated* dense cache, so a page that was
         partially cached before this prefill is rewritten whole — its old
@@ -147,40 +181,19 @@ class PagedKVPool:
         page-aligned (the kernel's contract)."""
         if n_new_pages <= 0:
             return
-        page = self.page_size
-        interp = _interp(interpret)
-        bt_tail = jnp.asarray(
-            block_table[start_page:start_page + n_new_pages], jnp.int32)[None]
-        nvalid = jnp.full((1,), n_new_pages, jnp.int32)
-        s0, span = start_page * page, n_new_pages * page
+        s0, span = start_page * self.page_size, n_new_pages * self.page_size
 
-        def rows(leaf_k):                      # (..., 1, cap, f) -> new KV rows
-            return leaf_k[..., 0, s0:s0 + span, :].reshape(
-                leaf_k.shape[:-3] + (span, self.hkv, self.hd))
+        def rows(c):                           # (..., 1, cap, F) -> new rows
+            return {"k": c["k"][..., 0, s0:s0 + span, :],
+                    "v": c["v"][..., 0, s0:s0 + span, :]}
 
-        for g in self.k_groups:
-            # ONE kernel launch per group: the stacked (n_full, P, page, H, D)
-            # layer axis folds into paged_write's batch axis by flattening the
-            # pool to (n_full * P, ...) and offsetting each layer's block
-            # table by its pool stride — no per-layer Python loop, and no
-            # per-prefill ``jnp.stack`` rebuild of the group array.
-            kc, vc = rows(cache["groups"][g]["k"]), rows(cache["groups"][g]["v"])
-            kg, vg = self.k_groups[g], self.v_groups[g]
-            P = kg.shape[1]
-            off = (jnp.arange(self.n_full, dtype=jnp.int32) * P)[:, None]
-            bt_l = bt_tail[0][None] + off                     # (n_full, npages)
-            nv_l = jnp.broadcast_to(nvalid, (self.n_full,))
-            kp, vp = paged_write(kc, vc,
-                                 kg.reshape((self.n_full * P,) + kg.shape[2:]),
-                                 vg.reshape((self.n_full * P,) + vg.shape[2:]),
-                                 bt_l, nv_l, interpret=interp)
-            self.k_groups[g] = kp.reshape(kg.shape)
-            self.v_groups[g] = vp.reshape(vg.shape)
-        for i in range(len(self.k_tail)):
-            kc, vc = rows(cache["tail"][i]["k"]), rows(cache["tail"][i]["v"])
-            self.k_tail[i], self.v_tail[i] = paged_write(
-                kc[None], vc[None], self.k_tail[i], self.v_tail[i],
-                bt_tail, nvalid, interpret=interp)
+        new = _write_pages_jit(
+            self.decode_state(),
+            {"groups": {g: rows(c) for g, c in cache["groups"].items()},
+             "tail": [rows(c) for c in cache["tail"]]},
+            jnp.asarray(block_table[start_page:start_page + n_new_pages],
+                        jnp.int32))
+        self.absorb_decode_state(new)
 
     # ------------------------------------------------------------------
     # decode side
@@ -214,8 +227,8 @@ class PagedKVPool:
 
     def decode_state(self):
         """The pool's page buffers as ONE pytree, to be passed INTO a jitted
-        decode step as an argument (donate it on TPU: pages then update in
-        place instead of the per-step functional pool copy). Pair with
+        step as an argument (donate it on TPU: pages then update in place
+        instead of the per-step functional pool copy). Pair with
         ``absorb_decode_state`` on the step's return value."""
         return {"groups": {g: {"k": self.k_groups[g], "v": self.v_groups[g]}
                            for g in self.k_groups},
@@ -223,9 +236,9 @@ class PagedKVPool:
                          for k, v in zip(self.k_tail, self.v_tail)]}
 
     def absorb_decode_state(self, state) -> None:
-        """Store the page buffers a jitted decode step returned. After a
-        donated TPU step the previous buffers are invalid; off-TPU donation
-        is a no-op and this is a plain functional publish."""
+        """Store the page buffers a jitted step returned. After a donated TPU
+        step the previous buffers are invalid; off-TPU donation is off and
+        this is a plain functional publish."""
         for g in self.k_groups:
             self.k_groups[g] = state["groups"][g]["k"]
             self.v_groups[g] = state["groups"][g]["v"]
@@ -233,32 +246,3 @@ class PagedKVPool:
             self.k_tail[i] = state["tail"][i]["k"]
             self.v_tail[i] = state["tail"][i]["v"]
 
-    @staticmethod
-    def wire_decode_cache(state, block_tables, n_full: int):
-        """Wire a ``decode_state`` pytree + per-sequence block tables into a
-        model cache pytree (traceable: usable inside a jitted/vmapped step)."""
-        bt = jnp.asarray(block_tables, jnp.int32)
-        groups = {g: {"k_pages": st["k"], "v_pages": st["v"],
-                      "block_tables": jnp.broadcast_to(
-                          bt, (n_full,) + bt.shape)}
-                  for g, st in state["groups"].items()}
-        tail = [{"k_pages": st["k"], "v_pages": st["v"], "block_tables": bt}
-                for st in state["tail"]]
-        return {"groups": groups, "tail": tail}
-
-    def make_decode_cache(self, block_tables, state=None):
-        """Wire the pool + per-sequence block tables into a model cache
-        pytree for a batched decode step (see attention.attn_apply)."""
-        return self.wire_decode_cache(
-            self.decode_state() if state is None else state,
-            block_tables, self.n_full)
-
-    def absorb_decode_cache(self, new_cache):
-        """Publish the page arrays a decode step returned (functional update:
-        the step appended one KV row per sequence to its tail page)."""
-        for g in self.k_groups:
-            self.k_groups[g] = new_cache["groups"][g]["k_pages"]
-            self.v_groups[g] = new_cache["groups"][g]["v_pages"]
-        for i in range(len(self.k_tail)):
-            self.k_tail[i] = new_cache["tail"][i]["k_pages"]
-            self.v_tail[i] = new_cache["tail"][i]["v_pages"]

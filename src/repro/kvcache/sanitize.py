@@ -24,7 +24,7 @@ scheduler step boundary (``LocalDisaggEngine(..., sanitize=True)``):
   in-flight request, decode sequence) explicitly references them, and a
   leaked ACTIVE relay page is diagnosed by name;
 - donation poisoning: ``SanitizedKVPool`` replaces the leaves of every
-  previously handed-out ``decode_state``/``make_decode_cache`` pytree with
+  previously handed-out ``decode_state``/``pool_state`` pytree with
   ``_PoisonedBuffer`` the moment the paired absorb lands — a read through a
   stale handle (which on TPU would be use-after-donation of a dead buffer)
   raises ``SanitizerError`` immediately, instead of silently reading valid
@@ -153,7 +153,7 @@ class _PoisonedBuffer:
         raise SanitizerError(
             f"use-after-donation: {op} on a page buffer that was donated "
             f"into {object.__getattribute__(self, '_why')} — re-fetch state "
-            f"via decode_state()/make_decode_cache() after every absorb")
+            f"via decode_state()/pool_state() after every absorb")
 
     def __getattr__(self, name):
         self._trap(f"attribute access .{name}")
@@ -225,17 +225,6 @@ class SanitizedKVPool(PagedKVPool):
         self._outstanding = [t for t in self._outstanding if t is not state]
         self._retire("a donated decode step (absorb_decode_state)")
         super().absorb_decode_state(state)
-
-    def make_decode_cache(self, block_tables, state=None):
-        cache = super().make_decode_cache(block_tables, state)
-        self._outstanding.append(cache)
-        return cache
-
-    def absorb_decode_cache(self, new_cache) -> None:
-        self._outstanding = [t for t in self._outstanding
-                             if t is not new_cache]
-        self._retire("a donated decode step (absorb_decode_cache)")
-        super().absorb_decode_cache(new_cache)
 
     def copy_page(self, src: int, dst: int) -> None:
         # the CoW clone donates the whole pool pytree on TPU: any state

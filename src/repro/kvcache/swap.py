@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime import donating_jit
+
 
 def next_pow2(n: int) -> int:
     """Smallest power of two >= n (>= 1): the page-count shape bucket."""
@@ -35,15 +37,15 @@ def next_pow2(n: int) -> int:
 
 
 def _gather_impl(state, bids):
-    # group arrays are (n_full, P+1, page, Hkv, D): page axis 1; tail arrays
-    # are (P+1, page, Hkv, D): page axis 0 (same layout as copy_page).
+    # group arrays are (n_full, P+1, page, F): page axis 1; tail arrays are
+    # (P+1, page, F): page axis 0 (same layout as copy_page).
     return jax.tree.map(
-        lambda a: a[:, bids] if a.ndim == 5 else a[bids], state)
+        lambda a: a[:, bids] if a.ndim == 4 else a[bids], state)
 
 
 def _scatter_impl(state, bids, vals):
     def sc(a, v):
-        if a.ndim == 5:
+        if a.ndim == 4:
             return a.at[:, bids].set(v)
         return a.at[bids].set(v)
     return jax.tree.map(sc, state, vals)
@@ -53,9 +55,7 @@ def _scatter_impl(state, bids, vals):
 # it wholesale, so the pool pytree is donated where donation is honoured —
 # exactly the copy_page contract, one launch per swap either way.
 _gather_jit = jax.jit(_gather_impl)
-_scatter_jit = jax.jit(
-    _scatter_impl,
-    donate_argnums=(0,) if jax.default_backend() == "tpu" else ())
+_scatter_jit = donating_jit(_scatter_impl, (0,))
 
 
 class HostSwapPool:
@@ -97,7 +97,7 @@ class HostSwapPool:
         dt = time.perf_counter() - t0
         # drop the pad rows landed by the pow2 bucket
         host = jax.tree.map(
-            lambda a: a[:, :n] if a.ndim == 5 else a[:n], host)
+            lambda a: a[:, :n] if a.ndim == 4 else a[:n], host)
         nbytes = n * kvpool.page_bytes
         self._entries[rid] = {"bids": list(bids), "host": host}
         self.total_bytes += nbytes
@@ -121,11 +121,11 @@ class HostSwapPool:
         def pick(a):
             # page axis sized to the pow2 bucket up front; pad slots stay
             # zero and scatter onto sentinel row 0 (never read as live KV)
-            axis = 1 if a.ndim == 5 else 0
+            axis = 1 if a.ndim == 4 else 0
             shape = (list(a.shape[:axis]) + [next_pow2(n)]
                      + list(a.shape[axis + 1:]))
             out = np.zeros(shape, a.dtype)
-            if a.ndim == 5:
+            if a.ndim == 4:
                 out[:, :n] = a[:, sel]
             else:
                 out[:n] = a[sel]

@@ -31,12 +31,14 @@ def run_engine(args):
     import numpy as np
     from repro.configs.base import ModelConfig
     from repro.models import init_params
+    from repro.runtime import setup_compile_cache
     from repro.serving.api import SamplingParams
     from repro.serving.engine import LocalDisaggEngine
 
     cfg = ModelConfig(name="local", arch_type="dense", n_layers=3,
                       d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
                       vocab_size=64, dtype="float32")
+    setup_compile_cache()
     base = init_params(cfg, jax.random.PRNGKey(0))
     decs = {f"agent{i}": init_params(cfg, jax.random.PRNGKey(3 + i))
             for i in range(args.agents)}

@@ -23,6 +23,7 @@ from jax import lax
 from repro.configs.base import LOCAL_ATTN
 from repro.models.layers import dense_init, rmsnorm
 from repro.models.rope import apply_rope
+from repro.runtime import on_tpu
 
 _NEG = -1e30
 
@@ -244,67 +245,81 @@ def attention(q, k, v, q_pos, k_pos, *, window: int = 0, softcap=None,
 PAGED_CACHE_KEYS = ("k_pages", "v_pages", "block_tables")
 
 
+def _use_kernels() -> bool:
+    """Mosaic kernels on TPU; the jnp twins in ``kernels.ref`` elsewhere
+    (the CPU tests). A test compiling for a described TPU steers this."""
+    return on_tpu()
+
+
 def paged_attention_step(q, k_pages, v_pages, block_tables, lengths, *,
-                         softcap=0.0):
+                         layer=None, softcap=0.0):
     """One decode step of attention over the paged KV pool.
 
-    q: (B, Hq, D); k/v_pages: (P, page, Hkv, D); block_tables: (B, npages);
-    lengths: (B,). Mosaic kernel on TPU; elsewhere the pure-jnp gather twin
+    q: (B, Hq, D); k/v_pages: (P, page, Hkv*D), or layer-stacked (L, P, page,
+    Hkv*D) read at ``layer``; block_tables: (B, npages); lengths: (B,).
+    Mosaic kernel on TPU; elsewhere the pure-jnp gather twin
     (kernels.ref.ref_paged_decode) — same contract, XLA-lowerable, and
     bit-compatible with the ``_direct`` dense path so paged and dense engines
-    produce identical greedy tokens.
+    produce identical greedy tokens on the CPU.
     """
-    if jax.default_backend() == "tpu":
+    if _use_kernels():
         from repro.kernels.paged_decode import paged_decode_attention
         return paged_decode_attention(q, k_pages, v_pages, block_tables,
-                                      lengths, softcap=softcap or 0.0)
+                                      lengths, layer=layer,
+                                      softcap=softcap or 0.0)
     from repro.kernels.ref import ref_paged_decode
     return ref_paged_decode(q, k_pages, v_pages, block_tables, lengths,
-                            softcap=softcap or 0.0)
+                            layer=layer, softcap=softcap or 0.0)
 
 
 def paged_prefill_attention(q, k_pages, v_pages, block_tables, start, *,
-                            softcap=0.0):
+                            layer=None, softcap=0.0):
     """Chunk-prefill attention over the paged KV pool.
 
     q: (B, S, Hq, D) chunk queries at absolute positions ``start[b] + i``;
     the chunk's own K/V rows are already in their pages. Mosaic kernel on
     TPU; elsewhere the pure-jnp gather twin (kernels.ref.ref_paged_prefill)
     — same contract and bit-compatible with the ``_direct`` dense path, so
-    chunked and unchunked prefill produce identical greedy tokens.
+    chunked and unchunked prefill produce identical greedy tokens on the CPU.
     """
-    if jax.default_backend() == "tpu":
+    if _use_kernels():
         from repro.kernels.flash_prefill_paged import flash_prefill_paged
         return flash_prefill_paged(q, k_pages, v_pages, block_tables, start,
-                                   softcap=softcap or 0.0)
+                                   layer=layer, softcap=softcap or 0.0)
     from repro.kernels.ref import ref_paged_prefill
     return ref_paged_prefill(q, k_pages, v_pages, block_tables, start,
-                             softcap=softcap or 0.0)
+                             layer=layer, softcap=softcap or 0.0)
 
 
 def _paged_apply(p, q, k, v, cache, pos, cfg):
     """Scatter the incoming tokens' K/V into their (private) pool pages,
     then attend over the block table. q/k/v: post-rope (B, S, H, D).
 
-    S == 1 is the decode step (paged_attention_step); S > 1 is a prefill
-    chunk (paged_prefill_attention) — both read the prefix straight from the
+    The pool is one layer's ``(P, page, Hkv*D)`` pages, or the whole
+    layer-stacked pool plus ``cache["layer"]`` (the layer scan carries the
+    pool and updates it in place; the kernels read the layer by index, so no
+    layer is ever sliced out). S == 1 is the decode step
+    (paged_attention_step); S > 1 is a prefill chunk
+    (paged_prefill_attention) — both read the prefix straight from the
     pages, no dense gather.
     """
     B, S = q.shape[0], q.shape[1]
     kp, vp, bt = (cache[key] for key in PAGED_CACHE_KEYS)
-    page = kp.shape[1]
+    layer = cache.get("layer")
+    page = kp.shape[-2]
     positions = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None]   # (B, S)
     pg = jnp.take_along_axis(bt, positions // page, axis=1)           # (B, S)
     slot = positions % page
+    at = (pg, slot) if layer is None else (layer, pg, slot)
     # vectorized scatter; written pages are private per sequence (fresh
     # chunk pages / copy-on-write at handoff), so (pg, slot) never collide.
-    kp = kp.at[pg, slot].set(k)
-    vp = vp.at[pg, slot].set(v)
+    kp = kp.at[at].set(k.reshape(B, S, -1))
+    vp = vp.at[at].set(v.reshape(B, S, -1))
     if S == 1:
-        o = paged_attention_step(q[:, 0], kp, vp, bt, pos + 1,
+        o = paged_attention_step(q[:, 0], kp, vp, bt, pos + 1, layer=layer,
                                  softcap=cfg.attn_softcap)[:, None]
     else:
-        o = paged_prefill_attention(q, kp, vp, bt, pos,
+        o = paged_prefill_attention(q, kp, vp, bt, pos, layer=layer,
                                     softcap=cfg.attn_softcap)
     out = jnp.einsum("bse,ed->bsd", o.reshape(B, S, -1), p["wo"])
     return out, {"k_pages": kp, "v_pages": vp, "block_tables": bt}

@@ -157,9 +157,8 @@ def _apply_layer(lp, x, cfg, kind, cache, pos, enc_out, flash, causal=True):
     if kind in (ATTN, LOCAL_ATTN):
         acache = None
         if cache is not None:
-            keys = (attn_mod.PAGED_CACHE_KEYS if "k_pages" in cache
-                    else ("k", "v", "kpos"))
-            acache = {k: cache[k] for k in keys}
+            acache = (cache if "k_pages" in cache
+                      else {k: cache[k] for k in ("k", "v", "kpos")})
         out, new_acache = attn_mod.attn_apply(
             lp["attn"], h, cfg, kind, cache=acache, pos=pos, causal=causal,
             flash=flash)
@@ -260,26 +259,43 @@ def forward(cfg: ModelConfig, params: Params, tokens, *, cache: Optional[Cache] 
 
     pat, n_full, tail = _group_structure(cfg)
     aux = _aux_zero()
+    gc = cache["groups"] if cache is not None and n_full else None
+    # Paged KV (``kvcache.paged``): the layer-stacked pool rides in the scan
+    # CARRY and each layer writes its rows into it in place, reading its own
+    # layer by index. As scan xs/ys it would be sliced per layer and rebuilt
+    # as a second whole pool on every call.
+    pool = gc if gc is not None and "k_pages" in gc["pos0"] else None
+    if pool is not None:
+        gc = {key: {"layer": jnp.arange(n_full, dtype=jnp.int32)}
+              for key in pool}
 
-    def group_body(x, slc):
+    def group_body(carry, slc):
+        x, pool = carry
         gp, gc = slc
         a = _aux_zero()
-        new_gc = {} if gc is not None else None
+        new_gc = {} if gc is not None and pool is None else None
         for i, kind in enumerate(pat):
-            ci = gc[f"pos{i}"] if gc is not None else None
+            key = f"pos{i}"
+            ci = gc[key] if gc is not None else None
+            if pool is not None:
+                ci = {**pool[key], **ci}
             x = _constrain(x)
-            x, nc, ax = _apply_layer(gp[f"pos{i}"], x, cfg, kind, ci, pos,
+            x, nc, ax = _apply_layer(gp[key], x, cfg, kind, ci, pos,
                                      enc_out, flash)
             a = _aux_add(a, ax)
-            if new_gc is not None:
-                new_gc[f"pos{i}"] = nc
-        return x, (new_gc, a)
+            if pool is not None:
+                pool = {**pool, key: {k: nc[k]
+                                      for k in attn_mod.PAGED_CACHE_KEYS}}
+            elif new_gc is not None:
+                new_gc[key] = nc
+        return (x, pool), (new_gc, a)
 
     if n_full:
         body = jax.checkpoint(group_body) if remat else group_body
-        gp = params["groups"]
-        gc = cache["groups"] if cache is not None else None
-        x, (new_groups, auxs) = lax.scan(body, x, (gp, gc))
+        (x, pool), (new_groups, auxs) = lax.scan(
+            body, (x, pool), (params["groups"], gc))
+        if pool is not None:
+            new_groups = pool
         aux = jax.tree.map(lambda a: a.sum(0), auxs)
     else:
         new_groups = {}

@@ -1,19 +1,21 @@
-"""Fused cross-model decode plane: stacked decoders, ONE vmapped step.
+"""Fused cross-model decode plane: every decode module, ONE jitted step.
 
 The paper's decode pool hosts many task-specific modules over one shared
 prefill KV pool — but a per-model dispatch loop pays one jitted forward (and
-one retrace key) per decode model per engine step. Since every decode module
+one retrace key) per decode model per engine step. Every decode module
 sharing a ``ModelConfig`` is structurally identical (full fine-tunes and
-LoRA merges alike), their param pytrees stack on a leading model axis
-(``core.lora.stack_params``), and one ``vmap`` over that axis advances EVERY
-active sequence of EVERY model in a single jitted forward per step.
+LoRA merges alike), so one jitted step advances EVERY active sequence of
+EVERY model: one model lane per module, each lane a forward over that
+module's own weights and its own rows of the batch.
 
 Weight layout per group (``DecodeModelSpec.group_key``):
-  - FULL specs stack complete param pytrees: every leaf is (M, ...).
+  - FULL specs keep each module's own param pytree (the registry's arrays,
+    never restacked: a stacked copy would hold every decoder's weights
+    twice on the device).
   - LORA specs stack ONLY the low-rank A/B factors (``stack_lora_params``);
-    the frozen base weights enter the step once, UNBATCHED, and each lane
-    merges ``W + scale * A[m] @ B[m]`` inside the jitted step right before
-    its forward — the decode plane stores one base copy + M adapter sets
+    the frozen base weights enter the step once, and each lane merges
+    ``W + scale * A[m] @ B[m]`` inside the jitted step right before its
+    forward — the decode plane stores one base copy + M adapter sets
     instead of M materialized full models (Eq. 9 on the weight side), and
     the merge is asserted bit-identical to pre-merged ``lora_apply``
     decoders (tests/test_registry.py).
@@ -30,13 +32,11 @@ Layout per step (``StackedDecoders.step``):
     stop scaling with prompt length (growth by one page within a bucket
     reuses the trace);
   - the pool's page buffers enter the jitted step as ONE donated-on-TPU
-    pytree (``PagedKVPool.decode_state``), so pages update in place instead
-    of the per-step functional pool copy;
-  - inside the step, each model lane runs the unchanged paged decode forward
-    over a lane-local view of the pool; the ONE fresh KV row each real
-    sequence wrote is gathered back out of its lane and scattered into the
-    shared pool — bit-exact, because pages are private per sequence (the
-    lane-local copies are dead after the gather and fuse away).
+    pytree (``PagedKVPool.decode_state``) and thread through the lanes one
+    after another: each lane's forward carries the pool through its layer
+    scan and writes its sequences' fresh rows in place. Pages are private
+    per sequence, so a lane never reads another lane's rows, and no lane
+    holds a copy of the pool.
 
 Per-request SamplingParams execute INSIDE the fused step (serving/
 sampling.py): each real sequence's logits row is gathered out of its lane
@@ -45,9 +45,6 @@ invariant — while temperature=0 rows take the exact argmax graph, keeping
 greedy outputs asserted identical to the per-model loop
 (tests/test_fused_decode.py); the per-model path remains available as
 ``LocalDisaggEngine(fused=False)`` for comparison.
-
-On TPU the vmapped lanes lower the paged-attention Pallas kernel through its
-batching rule; off-TPU the pure-jnp gather twin vmaps natively.
 """
 from __future__ import annotations
 
@@ -55,8 +52,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.lora import lora_apply, stack_lora_params, stack_params
-from repro.models import forward
+from repro.core.lora import lora_apply, stack_lora_params
+from repro.core.prefillshare import paged_forward
+from repro.runtime import donating_jit
 from repro.serving.sampling import sample_step
 
 
@@ -95,17 +93,16 @@ def group_specs(specs):
 
 
 class StackedDecoders:
-    """All decode modules of ONE fusable group, stacked for the fused step."""
+    """All decode modules of ONE fusable group, the lanes of one fused step."""
 
     def __init__(self, cfg, members: dict, kvpool, base_params=None):
         """``members``: {model_id: DecodeModelSpec}, all sharing ``cfg`` and
         one ``group_key``. ``base_params`` (the engine's single frozen copy)
         is required for LoRA groups — it is NOT copied: the stacked storage
-        is just the A/B factors."""
+        is just the A/B factors. Full groups hold the members' own arrays."""
         assert members, "need at least one decode module"
         self.cfg = cfg
         self.kvpool = kvpool
-        self.page_size = kvpool.page_size
         self.model_ids = sorted(members)             # stable model-axis order
         self.index = {mid: m for m, mid in enumerate(self.model_ids)}
         specs = [members[mid] for mid in self.model_ids]
@@ -120,7 +117,7 @@ class StackedDecoders:
                             "ab": stack_lora_params(
                                 [s.lora.params for s in specs])}
         else:
-            self.stacked = stack_params([s.full for s in specs])
+            self.stacked = [s.full for s in specs]
         self.traces = 0                              # jit retraces (tests)
         self.dispatches = 0                          # jitted-step invocations
         self._step = self._build_step()
@@ -134,63 +131,33 @@ class StackedDecoders:
 
     # ------------------------------------------------------------------
     def _build_step(self):
-        cfg, n_full, page = self.cfg, self.kvpool.n_full, self.page_size
-        wire = self.kvpool.wire_decode_cache
+        cfg = self.cfg
+        n_lanes = len(self.model_ids)
         if self.lora:
             alpha, rank = self.alpha, self.rank
-            # vmap axes: base broadcast (None — every lane reads the ONE
-            # copy), adapters split on their stacked model axis
-            param_axes = {"base": None, "ab": 0}
 
-            def lane_params(packed):
+            def lane_params(packed, m):
                 # the Eq. 9 weight-side merge, INSIDE the jitted step: the
                 # lane's effective weights exist only as an intermediate of
                 # this trace, never as M materialized models in the pool
-                return lora_apply(packed["base"], packed["ab"],
-                                  alpha=alpha, rank=rank)
+                ab = jax.tree.map(lambda x: x[m], packed["ab"])
+                return lora_apply(packed["base"], ab, alpha=alpha, rank=rank)
         else:
-            param_axes = 0
-
-            def lane_params(packed):
-                return packed
+            def lane_params(packed, m):
+                return packed[m]
 
         def fused(stacked, state, toks, pos, bts, seq_m, seq_b,
                   temps, top_ks, top_ps, seeds, greedy_only):
             # Python body runs once per trace: count retraces here.
             self.traces += 1
-
-            def lane(packed, t, p, bt):
-                cache = wire(state, bt, n_full)      # state: shared, unbatched
-                logits, new_cache, _ = forward(cfg, lane_params(packed),
-                                               t[:, None], cache=cache, pos=p)
-                return logits, new_cache
-
-            lg_all, caches = jax.vmap(lane, in_axes=(param_axes, 0, 0, 0))(
-                stacked, toks, pos, bts)
-            # Each real sequence wrote exactly ONE row, at (page, slot) named
-            # by its own block table — gather those rows out of the lane-local
-            # pool copies and scatter them into the shared state. Pages are
-            # private per sequence (sentinel page 0 absorbs fake-row writes),
-            # so indices never collide and the merge is bit-exact.
-            pg_all = jnp.take_along_axis(bts, (pos // page)[..., None],
-                                         axis=2)[..., 0]            # (M, Bmax)
-            pg = pg_all[seq_m, seq_b]
-            slot = (pos % page)[seq_m, seq_b]                       # (N,)
-            new_groups = {}
-            for g, st in state["groups"].items():
-                ko = caches["groups"][g]["k_pages"]  # (M, n_full, P, pg, H, D)
-                vo = caches["groups"][g]["v_pages"]
-                rk = jnp.moveaxis(ko[seq_m, :, pg, slot], 0, 1)  # (n_full,N,H,D)
-                rv = jnp.moveaxis(vo[seq_m, :, pg, slot], 0, 1)
-                new_groups[g] = {"k": st["k"].at[:, pg, slot].set(rk),
-                                 "v": st["v"].at[:, pg, slot].set(rv)}
-            new_tail = []
-            for i, st in enumerate(state["tail"]):
-                ko = caches["tail"][i]["k_pages"]    # (M, P, page, H, D)
-                vo = caches["tail"][i]["v_pages"]
-                new_tail.append(
-                    {"k": st["k"].at[pg, slot].set(ko[seq_m, pg, slot]),
-                     "v": st["v"].at[pg, slot].set(vo[seq_m, pg, slot])})
+            # lanes run one after another over the ONE pool: each writes its
+            # sequences' rows in place (fake rows land on sentinel page 0)
+            lanes = []
+            for m in range(n_lanes):
+                logits, state = paged_forward(cfg, lane_params(stacked, m),
+                                              toks[m][:, None], state,
+                                              bts[m], pos[m])
+                lanes.append(logits)
             # per-request sampling, INSIDE the fused step (no extra
             # dispatch): each real sequence's logits row is gathered out of
             # its lane and sampled with a key folded from (seed, position) —
@@ -198,18 +165,17 @@ class StackedDecoders:
             # (serving/sampling.py), keeping greedy outputs bit-identical.
             # greedy_only is STATIC: an all-greedy batch traces an
             # argmax-only step, paying none of the sampling graph.
-            lg = lg_all[seq_m, seq_b]                               # (N, V)
+            lg = jnp.stack(lanes)[seq_m, seq_b]                     # (N, V)
             if greedy_only:
                 nxt = jnp.argmax(lg, -1).astype(jnp.int32)
             else:
                 nxt = sample_step(lg, pos[seq_m, seq_b], temps, top_ks,
                                   top_ps, seeds)
-            return nxt, {"groups": new_groups, "tail": new_tail}
+            return nxt, state
 
-        # donate the pool buffers (arg 1) where donation is honoured, so the
-        # fused step appends KV in place — mirrors kvcache.paged.copy_page
-        donate = (1,) if jax.default_backend() == "tpu" else ()
-        return jax.jit(fused, donate_argnums=donate, static_argnums=(11,))
+        # donate the pool buffers (arg 1) on TPU, so the fused step appends
+        # KV in place — mirrors kvcache.paged.copy_page
+        return donating_jit(fused, (1,), static_argnums=(11,))
 
     # ------------------------------------------------------------------
     def step(self, seqs) -> np.ndarray:
@@ -217,7 +183,7 @@ class StackedDecoders:
         in ONE jitted forward — sampled per each sequence's SamplingParams
         (greedy when temperature=0); returns next tokens aligned with
         ``seqs``. Tail pages must already cover position ``pos``."""
-        M, page = len(self.model_ids), self.page_size
+        M = len(self.model_ids)
         counts = [0] * M
         coords = []
         for s in seqs:

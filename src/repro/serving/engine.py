@@ -61,8 +61,8 @@ Data plane (pure global-attention archs, the paper's operating point):
     first (page-level copy-on-write) so concurrent decoders can append
     privately. ``handoff_bytes`` counts only the block-table metadata.
   - decode: all active sequences (across sessions AND decode models sharing
-    this config) advance one token per engine step in ONE fused, jitted,
-    vmapped forward over model-stacked decoder params (serving/decode.py;
+    this config) advance one token per engine step in ONE fused, jitted
+    step with one lane per decode module (serving/decode.py;
     ``fused=False`` restores the per-model dispatch loop), using the paged
     decode-attention step (Pallas kernel on TPU, jnp gather twin elsewhere),
     with generated KV appended to freshly allocated private pages. The pool's
@@ -106,7 +106,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.prefillshare import (base_prefill, base_prefill_paged,
-                                     cache_schema)
+                                     cache_schema, paged_forward)
 from repro.kvcache.blocks import BlockPool, PoolExhausted
 from repro.kvcache.handoff import HandoffChannel, transfer_cache
 from repro.kvcache.manager import CacheManager, CacheStats
@@ -114,6 +114,7 @@ from repro.kvcache.paged import PagedKVPool
 from repro.kvcache.radix import NullPrefixIndex, PrefixIndex
 from repro.kvcache.sanitize import PoolSanitizer, SanitizedKVPool
 from repro.models import forward
+from repro.runtime import donating_jit
 from repro.serving.api import (FINISH_ABORT, FINISH_LENGTH, RequestOutput,
                                SamplingParams, SharedContext)
 from repro.serving.autoscale import Autoscaler
@@ -515,39 +516,37 @@ class DecodeWorker:
         return self._dec_params
 
     # ---- paged continuous batching ----
-    def step(self, tokens, pos, cache, temps, top_ks, top_ps, seeds,
-             greedy_only):
+    def step(self, tokens, pos, state, block_tables, temps, top_ks, top_ps,
+             seeds, greedy_only):
         """One batched decode step: feed ``tokens`` (B,) at positions ``pos``
-        (B,), paged cache attached, per-sequence sampling controls (B,)-
-        aligned; returns (next_tokens (B,), new_cache). Sampling runs inside
-        the jitted step; temperature=0 rows are exact argmax (the historical
+        (B,) over the pool ``state`` (a ``decode_state``) through
+        ``block_tables``, per-sequence sampling controls (B,)-aligned;
+        returns (next_tokens (B,), new_state). Sampling runs inside the
+        jitted step; temperature=0 rows are exact argmax (the historical
         greedy path, bit-identical), and an all-greedy batch (``greedy_only``
         static flag) traces an argmax-only step with no sampling graph."""
         if self._step is None:
             cfg = self.cfg
 
-            def _step(params, toks, pos, cache, temps, top_ks, top_ps,
+            def _step(params, toks, pos, state, bt, temps, top_ks, top_ps,
                       seeds, greedy_only):
-                logits, new_cache, _ = forward(cfg, params, toks[:, None],
-                                               cache=cache, pos=pos)
+                logits, new_state = paged_forward(cfg, params, toks[:, None],
+                                                  state, bt, pos)
                 if greedy_only:
                     nxt = jnp.argmax(logits, -1).astype(jnp.int32)
                 else:
                     nxt = sample_step(logits, pos, temps, top_ks, top_ps,
                                       seeds)
-                return nxt, new_cache
+                return nxt, new_state
 
             # jit keyed on (B, npages) shapes + the binary greedy_only flag;
             # retraces only when the batch composition or table width
             # changes (sampling controls are VALUES, never trace keys). The
-            # cache (pool pages + block tables) is donated where donation is
-            # honoured, so the step appends KV in place;
-            # make_decode_cache/absorb_decode_cache are the donation-aware
-            # pair around this call.
-            donate = (3,) if jax.default_backend() == "tpu" else ()
-            self._step = jax.jit(_step, donate_argnums=donate,
-                                 static_argnums=(8,))
-        return self._step(self.dec_params, tokens, pos, cache,
+            # pool state is donated on TPU, so the step appends KV in place;
+            # decode_state/absorb_decode_state are the donation-aware pair
+            # around this call.
+            self._step = donating_jit(_step, (3,), static_argnums=(9,))
+        return self._step(self.dec_params, tokens, pos, state, block_tables,
                           temps, top_ks, top_ps, seeds, greedy_only)
 
     # ---- dense fallback ----
@@ -675,10 +674,10 @@ class LocalDisaggEngine:
                                    else NullPrefixIndex(page_size))
                 for _ in range(n_prefill_workers)]
         self.prefill = self.prefill_workers[0]        # 1-worker convenience
-        # fused cross-model decode (serving.decode): stack the decoder param
-        # pytrees and advance every sequence of every model in ONE vmapped,
-        # jitted forward per step. Default on the paged plane; fused=False
-        # keeps the per-model dispatch loop (comparison/regression path).
+        # fused cross-model decode (serving.decode): advance every sequence
+        # of every model in ONE jitted step, one lane per decode module.
+        # Default on the paged plane; fused=False keeps the per-model
+        # dispatch loop (comparison/regression path).
         self.fused = self.paged if fused is None else fused
         assert not (self.fused and not self.paged), \
             "fused decode requires the paged data plane"
@@ -1377,7 +1376,7 @@ class LocalDisaggEngine:
     def decode_step(self, seqs: list[DecodeSeq]) -> None:
         """Advance every active sequence — across ALL decode models — one
         token, sampled per each request's SamplingParams (temperature=0:
-        exact greedy). Fused mode (default): ONE jitted vmapped forward per
+        exact greedy). Fused mode (default): ONE jitted step per engine
         step per distinct decode config (one total here, every decoder shares
         the engine config). fused=False: the per-model dispatch loop.
 
@@ -1448,10 +1447,10 @@ class LocalDisaggEngine:
             bt[i, :len(s.block_table)] = s.block_table
         toks = jnp.asarray([s.next_token for s in seqs], jnp.int32)
         pos = jnp.asarray([s.pos for s in seqs], jnp.int32)
-        cache = self.kvpool.make_decode_cache(bt)
-        nxt, new_cache = self.decoders[mid].step(toks, pos, cache,
-                                                 *sampling_arrays(seqs))
-        self.kvpool.absorb_decode_cache(new_cache)
+        nxt, new_state = self.decoders[mid].step(
+            toks, pos, self.kvpool.decode_state(), jnp.asarray(bt),
+            *sampling_arrays(seqs))
+        self.kvpool.absorb_decode_state(new_state)
         self.stats.decode_dispatches += 1
         return np.asarray(nxt)
 

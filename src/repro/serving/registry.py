@@ -28,11 +28,11 @@ Lifecycle semantics:
     baseline) and removes the model at the next step boundary.
 
 Weight layout per spec kind (serving/decode.py):
-  - ``full=params``: the model's full pytree joins the stacked model axis.
+  - ``full=params``: the model's own pytree drives one lane of the step.
   - ``lora=LoRAAdapter(...)``: only the (tiny) stacked A/B factors are
     stored; the frozen base weights are the ENGINE's single copy, and the
-    merge ``W + scale * A[m] @ B[m]`` happens inside the jitted vmapped
-    decode step — one base copy + N adapter sets instead of N full models
+    merge ``W + scale * A[m] @ B[m]`` happens inside the jitted decode
+    step — one base copy + N adapter sets instead of N full models
     (Eq. 9 on the weight side).
 """
 from __future__ import annotations
@@ -62,7 +62,7 @@ class DecodeModelSpec:
     """How a registered decode model stores its weights: exactly one of
 
     - ``full=params``   — a complete param pytree (the paper's full
-      fine-tunes); the fused plane stacks it on the model axis.
+      fine-tunes); the fused plane runs one lane over it.
     - ``lora=LoRAAdapter(...)`` — base + low-rank factors; the fused plane
       stores only the stacked factors and merges inside the step.
     """

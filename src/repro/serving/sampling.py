@@ -1,7 +1,7 @@
 """Per-sequence token sampling for the decode planes (pure jnp, jit-safe).
 
 The serving API's ``SamplingParams`` are executed INSIDE the jitted decode
-step (fused vmapped plane and per-model loop alike), so sampling costs no
+step (fused plane and per-model loop alike), so sampling costs no
 extra dispatch. Two properties the tests pin:
 
   - ``temperature == 0`` is EXACTLY ``jnp.argmax(logits, -1)`` — the

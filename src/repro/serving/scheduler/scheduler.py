@@ -451,7 +451,7 @@ class ChunkedScheduler:
     def _decode_phase(self) -> int:
         """Advance every active sequence one token. Model grouping is the
         ENGINE's concern now: the fused decode plane batches all models
-        sharing a config into one vmapped forward (engine.decode_step), so
+        sharing a config into one jitted step (engine.decode_step), so
         the scheduler no longer splits the batch by model.
 
         The engine steps a COPY of the active list: stream callbacks fire

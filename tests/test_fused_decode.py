@@ -1,5 +1,5 @@
-"""Fused cross-model decode plane (serving/decode.py): one vmapped jitted
-forward per engine step for ALL decode models, bit-identical greedy tokens vs
+"""Fused cross-model decode plane (serving/decode.py): one jitted step per
+engine step for ALL decode models, bit-identical greedy tokens vs
 the per-model dispatch loop, donation-aware pool updates, power-of-two
 block-table bucketing, and the page-0 padding sentinel."""
 import jax

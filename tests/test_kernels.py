@@ -59,8 +59,8 @@ def test_paged_decode_vs_ref(case, dtype):
     B, Hq, Hkv, D, page, npages, P = case
     ks = jax.random.split(KEY, 5)
     q = jax.random.normal(ks[0], (B, Hq, D), dtype)
-    kp = jax.random.normal(ks[1], (P, page, Hkv, D), dtype)
-    vp = jax.random.normal(ks[2], (P, page, Hkv, D), dtype)
+    kp = jax.random.normal(ks[1], (P, page, Hkv * D), dtype)
+    vp = jax.random.normal(ks[2], (P, page, Hkv * D), dtype)
     bt = jax.random.randint(ks[3], (B, npages), 0, P)
     maxlen = page * npages
     ln = jax.random.randint(ks[4], (B,), 1, maxlen + 1).astype(jnp.int32)
@@ -75,8 +75,8 @@ def test_paged_softcap():
     B, Hq, Hkv, D, page, npages, P = 2, 4, 2, 64, 16, 4, 12
     ks = jax.random.split(KEY, 5)
     q = jax.random.normal(ks[0], (B, Hq, D))
-    kp = jax.random.normal(ks[1], (P, page, Hkv, D))
-    vp = jax.random.normal(ks[2], (P, page, Hkv, D))
+    kp = jax.random.normal(ks[1], (P, page, Hkv * D))
+    vp = jax.random.normal(ks[2], (P, page, Hkv * D))
     bt = jax.random.randint(ks[3], (B, npages), 0, P)
     ln = jnp.array([30, 64], jnp.int32)
     o = paged_attention(q, kp, vp, bt, ln, softcap=30.0, interpret=True)
@@ -128,8 +128,8 @@ def test_flash_prefill_paged_vs_ref(case, dtype):
     B, Hq, Hkv, D, page, npages, P, S = case
     ks = jax.random.split(KEY, 5)
     q = jax.random.normal(ks[0], (B, S, Hq, D), dtype)
-    kp = jax.random.normal(ks[1], (P, page, Hkv, D), dtype)
-    vp = jax.random.normal(ks[2], (P, page, Hkv, D), dtype)
+    kp = jax.random.normal(ks[1], (P, page, Hkv * D), dtype)
+    vp = jax.random.normal(ks[2], (P, page, Hkv * D), dtype)
     bt = jax.random.randint(ks[3], (B, npages), 0, P)
     max_start = npages * page - S
     st = jax.random.randint(ks[4], (B,), 0, max_start + 1).astype(jnp.int32)
@@ -144,8 +144,8 @@ def test_flash_prefill_paged_softcap():
     B, Hq, Hkv, D, page, npages, P, S = 2, 4, 2, 64, 8, 4, 12, 6
     ks = jax.random.split(KEY, 5)
     q = jax.random.normal(ks[0], (B, S, Hq, D))
-    kp = jax.random.normal(ks[1], (P, page, Hkv, D))
-    vp = jax.random.normal(ks[2], (P, page, Hkv, D))
+    kp = jax.random.normal(ks[1], (P, page, Hkv * D))
+    vp = jax.random.normal(ks[2], (P, page, Hkv * D))
     bt = jax.random.randint(ks[3], (B, npages), 0, P)
     st = jnp.array([3, 20], jnp.int32)
     o = flash_prefill_paged(q, kp, vp, bt, st, softcap=30.0, interpret=True)
@@ -163,8 +163,8 @@ def test_flash_prefill_paged_matches_contiguous_flash():
     k = jax.random.normal(ks[0], (B, T, Hkv, D))
     v = jax.random.normal(ks[1], (B, T, Hkv, D))
     q_full = jax.random.normal(ks[2], (B, T, Hq, D))
-    kp = k.reshape(T // page, page, Hkv, D)
-    vp = v.reshape(T // page, page, Hkv, D)
+    kp = k.reshape(T // page, page, Hkv * D)
+    vp = v.reshape(T // page, page, Hkv * D)
     bt = jnp.arange(T // page, dtype=jnp.int32)[None]
     st = jnp.array([T - S], jnp.int32)
     o = flash_prefill_paged(q_full[:, T - S:], kp, vp, bt, st,
@@ -194,10 +194,10 @@ def test_paged_write_vs_ref(case, dtype):
     B, S, H, D, page, P = case
     npages = S // page
     ks = jax.random.split(KEY, 4)
-    nk = jax.random.normal(ks[0], (B, S, H, D), dtype)
-    nv = jax.random.normal(ks[1], (B, S, H, D), dtype)
-    kp = jax.random.normal(ks[2], (P, page, H, D), dtype)
-    vp = jax.random.normal(ks[3], (P, page, H, D), dtype)
+    nk = jax.random.normal(ks[0], (B, S, H * D), dtype)
+    nv = jax.random.normal(ks[1], (B, S, H * D), dtype)
+    kp = jax.random.normal(ks[2], (P, page, H * D), dtype)
+    vp = jax.random.normal(ks[3], (P, page, H * D), dtype)
     # disjoint page assignment across requests
     perm = np.random.RandomState(0).permutation(P)[: B * npages]
     bt = jnp.asarray(perm.reshape(B, npages), jnp.int32)
@@ -216,10 +216,10 @@ def test_paged_roundtrip_write_then_read():
     B, S, H, D, page, P = 2, 64, 2, 64, 16, 16
     npages = S // page
     ks = jax.random.split(KEY, 3)
-    nk = jax.random.normal(ks[0], (B, S, H, D))
-    nv = jax.random.normal(ks[1], (B, S, H, D))
-    kp = jnp.zeros((P, page, H, D))
-    vp = jnp.zeros((P, page, H, D))
+    nk = jax.random.normal(ks[0], (B, S, H * D))
+    nv = jax.random.normal(ks[1], (B, S, H * D))
+    kp = jnp.zeros((P, page, H * D))
+    vp = jnp.zeros((P, page, H * D))
     bt = jnp.arange(B * npages, dtype=jnp.int32).reshape(B, npages)
     nvalid = jnp.full((B,), npages, jnp.int32)
     kp, vp = paged_write(nk, nv, kp, vp, bt, nvalid, interpret=True)

@@ -9,7 +9,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.lora import (LoRAPair, cache_conditioned_lora_loss,
                              lora_apply, lora_init, lora_param_count,
-                             stack_lora_params, stack_params)
+                             stack_lora_params)
 from repro.models import init_params
 from repro.training import data as D
 from repro.training.optim import AdamW
@@ -66,19 +66,6 @@ def test_real_param_subtree_named_a_b_is_not_an_adapter():
     assert not np.array_equal(np.asarray(merged2["wq"]), np.asarray(base["wq"]))
     np.testing.assert_array_equal(np.asarray(merged2["factored"]["A"]),
                                   np.asarray(base["factored"]["A"]))
-
-
-def test_stack_params_model_axis():
-    """The fused decode plane's layout: N structurally-identical pytrees
-    stack leaf-wise on a NEW leading model axis, and slicing lane m back out
-    recovers model m's params bit-for-bit."""
-    ps = [init_params(CFG, jax.random.PRNGKey(s)) for s in range(3)]
-    stacked = stack_params(ps)
-    for leaf, l0 in zip(jax.tree.leaves(stacked), jax.tree.leaves(ps[0])):
-        assert leaf.shape == (3,) + l0.shape
-    for m, p in enumerate(ps):
-        for leaf, orig in zip(jax.tree.leaves(stacked), jax.tree.leaves(p)):
-            np.testing.assert_array_equal(np.asarray(leaf[m]), np.asarray(orig))
 
 
 def test_stack_lora_params_preserves_none_and_merge():

@@ -2,7 +2,7 @@
 models while the engine serves — duplicate/unknown-id errors, drain vs abort
 retirement, page refcounts back to baseline after churn, bit-identical
 surviving streams across fused-plane lane remaps, and LoRA-spec'd models
-(one base copy + stacked adapters, merged inside the jitted vmapped step)
+(one base copy + stacked adapters, merged inside the jitted decode step)
 asserted bit-identical to pre-merged ``lora_apply`` decoders."""
 import warnings
 

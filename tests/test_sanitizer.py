@@ -189,14 +189,17 @@ def test_use_after_donation_trips():
 
 
 def test_stale_decode_cache_trips():
+    """A pool handle taken before a chunked-prefill step (which donates the
+    pool on TPU) is dead after it."""
     base, decs = _params()
-    eng = _engine(base, decs, sanitize=True)
-    bt = np.zeros((1, 2), np.int32)
-    stale = eng.kvpool.make_decode_cache(bt)
+    eng = _engine(base, decs, sanitize=True, chunked=True, chunk_size=PAGE)
+    stale = eng.kvpool.decode_state()
     g = next(iter(stale["groups"]))
-    eng.kvpool.absorb_decode_cache(eng.kvpool.make_decode_cache(bt))
+    eng.generate("m0", list(range(1, 12)), SamplingParams(max_tokens=1))
+    eng.scheduler.step()                      # one prefill chunk
+    assert eng.scheduler.stats.chunks >= 1
     with pytest.raises(SanitizerError, match="use-after-donation"):
-        _ = stale["groups"][g]["k_pages"][0]
+        _ = stale["groups"][g]["k"][0]
 
 
 def test_absorbed_tree_itself_is_never_poisoned():

@@ -96,17 +96,19 @@ def test_tiny_sharded_execution_subprocess():
         "'--xla_force_host_platform_device_count=8'\n"
         "import sys; sys.path.insert(0, %r)\n"
         "import jax, jax.numpy as jnp, dataclasses\n"
+        "from jax.sharding import AxisType\n"
         "from repro.configs import get_config\n"
         "from repro.configs.base import INPUT_SHAPES, InputShape\n"
         "from repro.launch.steps import build\n"
         "cfg = get_config('internlm2-1.8b').reduced()\n"
         "cfg = dataclasses.replace(cfg, name='t', vocab_size=512)\n"
-        "mesh = jax.make_mesh((2, 4), ('data', 'model'))\n"
+        "mesh = jax.make_mesh((2, 4), ('data', 'model'),\n"
+        "                     axis_types=(AxisType.Auto,) * 2)\n"
         "INPUT_SHAPES['tiny_dec'] = InputShape('tiny_dec', 64, 4, 'decode')\n"
         "b = build(cfg, 'tiny_dec', mesh)\n"
-        "with mesh:\n"
+        "args = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), b['args'])\n"
+        "with jax.set_mesh(mesh):\n"
         "    f = jax.jit(b['fn'], in_shardings=b['in_shardings'])\n"
-        "    args = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), b['args'])\n"
         "    logits, cache = f(*args)\n"
         "    assert logits.shape == (4, cfg.vocab_size)\n"
         "    assert not bool(jnp.isnan(logits).any())\n"
