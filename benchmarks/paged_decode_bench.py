@@ -51,6 +51,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.lora import LoRAPair, lora_apply, lora_init
 from repro.models import init_params
+from repro.runtime import program_traces
 from repro.serving.api import SamplingParams
 from repro.serving.engine import LocalDisaggEngine
 from repro.serving.registry import DecodeModelSpec, LoRAAdapter
@@ -159,7 +160,8 @@ def multi_model(n_models: int = 4, seqs_per_model: int = 2, gen: int = 32,
               f"{r['tok_s']:.1f}")
     print(f"# fused speedup={fused_tps / loop_tps:.2f}x over per-model loop "
           f"(greedy outputs identical, {n_models} models, "
-          f"{len(jobs)} sequences, traces={eng.decode_plane.traces})")
+          f"{len(jobs)} sequences, "
+          f"traces={program_traces().get('decode_step', 0)})")
     return rows, fused_tps / loop_tps
 
 

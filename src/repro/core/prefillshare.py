@@ -120,23 +120,21 @@ def paged_forward(cfg: ModelConfig, params, tokens, state, block_tables, pos,
                  "tail": [unwire(c) for c in cache["tail"]]}
 
 
+#: one jitted chunk step per config. It retraces per distinct (B, S, npages)
+#: shape (``repro.runtime.PROGRAM_TRACES["chunk_step"]`` counts them): with
+#: the scheduler's power-of-two table bucketing, npages contributes O(log
+#: pages) retraces instead of one per page of prefix growth.
 _CHUNK_STEPS: dict = {}
-#: retrace counter per config (the trace-scaling tests read this): the jitted
-#: chunk step retraces per distinct (B, S, npages) shape — with the
-#: scheduler's power-of-two table bucketing, npages contributes O(log pages)
-#: retraces instead of one per page of prefix growth.
-CHUNK_TRACES: dict = {}
 
 
 def _make_chunk_step(cfg: ModelConfig):
     def _step(params, toks, pos, state, block_tables):
-        CHUNK_TRACES[cfg] = CHUNK_TRACES.get(cfg, 0) + 1   # once per trace
         _, new_state = paged_forward(cfg, params, toks, state, block_tables,
                                      pos, logits="hidden")
         return new_state
     # the pool (arg 3) is donated on TPU: each chunk appends its rows in
     # place instead of allocating a second pool
-    return donating_jit(_step, (3,))
+    return donating_jit(_step, (3,), name="chunk_step")
 
 
 def base_prefill_chunk(cfg: ModelConfig, base_params: Params, tokens, *,

@@ -65,7 +65,7 @@ def _copy_page_impl(state, src, dst):
 # One fused, jitted update over the whole pool pytree; donating the pool
 # buffers lets XLA update the touched pages in place instead of copying the
 # pool per clone.
-_copy_page_jit = donating_jit(_copy_page_impl, (0,))
+_copy_page_jit = donating_jit(_copy_page_impl, (0,), name="copy_page")
 
 
 def _write_pages_impl(state, rows, block_table):
@@ -98,7 +98,7 @@ def _write_pages_impl(state, rows, block_table):
     return new
 
 
-_write_pages_jit = donating_jit(_write_pages_impl, (0,))
+_write_pages_jit = donating_jit(_write_pages_impl, (0,), name="write_pages")
 
 
 class PagedKVPool:

@@ -55,7 +55,7 @@ def _scatter_impl(state, bids, vals):
 # it wholesale, so the pool pytree is donated where donation is honoured —
 # exactly the copy_page contract, one launch per swap either way.
 _gather_jit = jax.jit(_gather_impl)
-_scatter_jit = donating_jit(_scatter_impl, (0,))
+_scatter_jit = donating_jit(_scatter_impl, (0,), name="swap_scatter")
 
 
 class HostSwapPool:

@@ -1,4 +1,5 @@
-"""Process-level device plumbing: backend probe, buffer donation, compile cache.
+"""Process-level device plumbing: backend probe, buffer donation, named
+programs and their trace counts, compile cache.
 
 Nothing here runs at import time: importing ``repro`` initialises no JAX
 backend, so a launcher may still configure JAX (platform, compile cache)
@@ -7,7 +8,9 @@ never takes the accelerator.
 """
 from __future__ import annotations
 
+import functools
 import os
+from collections import Counter
 from pathlib import Path
 
 import jax
@@ -25,19 +28,44 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def donating_jit(fun, donate_argnums, **jit_kwargs):
-    """``jax.jit(fun)`` that donates ``donate_argnums`` on TPU only.
+#: traces per program name, process-wide: ``donating_jit``'s wrapped body
+#: runs once per trace, and each trace is one program compiled or loaded
+#: from the persistent compile cache
+PROGRAM_TRACES: Counter = Counter()
 
+
+def program_traces() -> dict:
+    """``{program name: traces so far}`` over every engine in the process."""
+    return dict(PROGRAM_TRACES)
+
+
+def donating_jit(fun, donate_argnums, *, name: str, **jit_kwargs):
+    """``jax.jit(fun)`` named ``name`` that donates ``donate_argnums`` on
+    TPU only.
+
+    The program's XLA module reads ``jit_<name>``; each trace adds one to
+    ``PROGRAM_TRACES[name]``; each call runs inside the profiler span
+    ``ps.run.<name>`` (dispatch: trace, lower, compile or load, enqueue).
     The backend is asked at the first call, not at definition, so a
     module-level step can be declared without initialising a backend."""
-    compiled = []
+    @functools.wraps(fun)
+    def traced(*args, **kwargs):
+        PROGRAM_TRACES[name] += 1
+        return fun(*args, **kwargs)
+
+    traced.__name__ = traced.__qualname__ = name
+    built = []
 
     def call(*args, **kwargs):
-        if not compiled:
-            compiled.append(jax.jit(
-                fun, donate_argnums=donate_argnums if on_tpu() else (),
-                **jit_kwargs))
-        return compiled[0](*args, **kwargs)
+        if not built:
+            # imported here: the serving package imports this module
+            from repro.serving.metrics import PS_RUN
+            built.append((jax.jit(
+                traced, donate_argnums=donate_argnums if on_tpu() else (),
+                **jit_kwargs), PS_RUN + name))
+        jitted, span = built[0]
+        with jax.profiler.TraceAnnotation(span):
+            return jitted(*args, **kwargs)
 
     call.__wrapped__ = fun
     return call

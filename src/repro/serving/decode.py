@@ -51,10 +51,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.lora import lora_apply, stack_lora_params
 from repro.core.prefillshare import paged_forward
 from repro.runtime import donating_jit
+from repro.serving.metrics import PS_DECODE_BUILD, PS_DECODE_READBACK
 from repro.serving.sampling import sample_step
 
 
@@ -118,7 +120,6 @@ class StackedDecoders:
                                 [s.lora.params for s in specs])}
         else:
             self.stacked = [s.full for s in specs]
-        self.traces = 0                              # jit retraces (tests)
         self.dispatches = 0                          # jitted-step invocations
         self._step = self._build_step()
 
@@ -148,8 +149,6 @@ class StackedDecoders:
 
         def fused(stacked, state, toks, pos, bts, seq_m, seq_b,
                   temps, top_ks, top_ps, seeds, greedy_only):
-            # Python body runs once per trace: count retraces here.
-            self.traces += 1
             # lanes run one after another over the ONE pool: each writes its
             # sequences' rows in place (fake rows land on sentinel page 0)
             lanes = []
@@ -175,7 +174,8 @@ class StackedDecoders:
 
         # donate the pool buffers (arg 1) on TPU, so the fused step appends
         # KV in place — mirrors kvcache.paged.copy_page
-        return donating_jit(fused, (1,), static_argnums=(11,))
+        return donating_jit(fused, (1,), name="decode_step",
+                            static_argnums=(11,))
 
     # ------------------------------------------------------------------
     def step(self, seqs) -> np.ndarray:
@@ -183,31 +183,33 @@ class StackedDecoders:
         in ONE jitted forward — sampled per each sequence's SamplingParams
         (greedy when temperature=0); returns next tokens aligned with
         ``seqs``. Tail pages must already cover position ``pos``."""
-        M = len(self.model_ids)
-        counts = [0] * M
-        coords = []
-        for s in seqs:
-            m = self.index[s.model_id]
-            coords.append((m, counts[m]))
-            counts[m] += 1
-        bmax = max(counts)
-        npages = next_pow2(max(len(s.block_table) for s in seqs))
-        toks = np.zeros((M, bmax), np.int32)
-        pos = np.zeros((M, bmax), np.int32)
-        bts = np.zeros((M, bmax, npages), np.int32)   # pad = sentinel page 0
-        for s, (m, b) in zip(seqs, coords):
-            toks[m, b] = s.next_token
-            pos[m, b] = s.pos
-            bts[m, b, :len(s.block_table)] = s.block_table
-        seq_m = jnp.asarray([m for m, _ in coords], jnp.int32)
-        seq_b = jnp.asarray([b for _, b in coords], jnp.int32)
+        with TraceAnnotation(PS_DECODE_BUILD):
+            M = len(self.model_ids)
+            counts = [0] * M
+            coords = []
+            for s in seqs:
+                m = self.index[s.model_id]
+                coords.append((m, counts[m]))
+                counts[m] += 1
+            bmax = max(counts)
+            npages = next_pow2(max(len(s.block_table) for s in seqs))
+            toks = np.zeros((M, bmax), np.int32)
+            pos = np.zeros((M, bmax), np.int32)
+            bts = np.zeros((M, bmax, npages), np.int32)  # pad: sentinel 0
+            for s, (m, b) in zip(seqs, coords):
+                toks[m, b] = s.next_token
+                pos[m, b] = s.pos
+                bts[m, b, :len(s.block_table)] = s.block_table
+            seq_m = jnp.asarray([m for m, _ in coords], jnp.int32)
+            seq_b = jnp.asarray([b for _, b in coords], jnp.int32)
+            args = (jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(bts),
+                    seq_m, seq_b, *sampling_arrays(seqs))
         nxt, new_state = self._step(self.stacked, self.kvpool.decode_state(),
-                                    jnp.asarray(toks), jnp.asarray(pos),
-                                    jnp.asarray(bts), seq_m, seq_b,
-                                    *sampling_arrays(seqs))
+                                    *args)
         self.kvpool.absorb_decode_state(new_state)
         self.dispatches += 1
-        return np.asarray(nxt)
+        with TraceAnnotation(PS_DECODE_READBACK):
+            return np.asarray(nxt)
 
 
 class FusedDecodePlane:
@@ -218,25 +220,21 @@ class FusedDecodePlane:
 
     The plane is an immutable snapshot of the registry's model set: churn
     (hot register/unregister) REPLACES it at a step boundary
-    (``LocalDisaggEngine._rebuild_decode_plane``), carrying the trace/
-    dispatch counters forward so stats stay cumulative across rebuilds."""
+    (``LocalDisaggEngine._rebuild_decode_plane``), carrying the dispatch
+    counter forward so stats stay cumulative across rebuilds. Its traces
+    are counted by program name in ``repro.runtime.PROGRAM_TRACES``."""
 
     def __init__(self, specs, kvpool, base_params=None, *,
-                 traces0: int = 0, dispatches0: int = 0):
+                 dispatches0: int = 0):
         """specs: {model_id: (cfg, DecodeModelSpec)}."""
         self.groups = [StackedDecoders(cfg, members, kvpool, base_params)
                        for (cfg, _k), members in group_specs(specs).items()]
         self._group_of = {mid: g for g in self.groups for mid in g.model_ids}
-        self._traces0 = traces0
         self._dispatches0 = dispatches0
 
     @property
     def model_ids(self) -> list:
         return sorted(self._group_of)
-
-    @property
-    def traces(self) -> int:
-        return self._traces0 + sum(g.traces for g in self.groups)
 
     @property
     def dispatches(self) -> int:

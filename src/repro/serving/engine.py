@@ -103,6 +103,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.prefillshare import (base_prefill, base_prefill_paged,
@@ -114,14 +115,15 @@ from repro.kvcache.paged import PagedKVPool
 from repro.kvcache.radix import NullPrefixIndex, PrefixIndex
 from repro.kvcache.sanitize import PoolSanitizer, SanitizedKVPool
 from repro.models import forward
-from repro.runtime import donating_jit
+from repro.runtime import donating_jit, program_traces
 from repro.serving.api import (FINISH_ABORT, FINISH_LENGTH, RequestOutput,
                                SamplingParams, SharedContext)
 from repro.serving.autoscale import Autoscaler
 from repro.serving.backpressure import ThroughputEWMA
 from repro.serving.decode import (FusedDecodePlane, next_pow2,
                                   sampling_arrays)
-from repro.serving.metrics import (SPAN_FIRST_TOKEN, SPAN_HANDOFF,
+from repro.serving.metrics import (PS_DECODE_BUILD, PS_DECODE_PUSH,
+                                   SPAN_FIRST_TOKEN, SPAN_HANDOFF,
                                    SPAN_ROUTED, SPAN_TOKEN, MetricsRegistry)
 from repro.serving.preempt import PreemptConfig, SwapManager
 from repro.serving.registry import ModelRegistry, as_spec
@@ -212,6 +214,10 @@ class EngineStats:
     prefill_tokens_reused = _CounterField(
         "engine_prefill_tokens_reused_total",
         "prompt tokens served from cached prefix KV (never recomputed)")
+    prefill_tokens_duplicated = _CounterField(
+        "engine_prefill_tokens_duplicated_total",
+        "prompt tokens admitted for prefill that an admitted, not yet "
+        "committed request is already computing")
     handoffs = _CounterField(
         "engine_handoffs_total", "prefill->decode cache handoffs")
     handoff_bytes = _CounterField(
@@ -257,7 +263,8 @@ class EngineStats:
         "engine_swap_bytes_total",
         "KV bytes moved device<->host by the swap tier")
 
-    FIELDS = ("prefill_tokens_computed", "prefill_tokens_reused", "handoffs",
+    FIELDS = ("prefill_tokens_computed", "prefill_tokens_reused",
+              "prefill_tokens_duplicated", "handoffs",
               "handoff_bytes", "cow_page_copies", "decode_steps",
               "decode_tokens", "decode_dispatches", "model_churn_events",
               "plane_rebuilds", "relay_publishes", "relay_pages_published",
@@ -296,6 +303,7 @@ class EngineStats:
         d = {name: getattr(self, name) for name in self.FIELDS}
         d["hit_ratio"] = self.hit_ratio
         d["decode_batch_mean"] = self.decode_batch_mean
+        d["program_traces"] = program_traces()
         eng = self._engine
         if eng is None:
             return d
@@ -545,7 +553,8 @@ class DecodeWorker:
             # pool state is donated on TPU, so the step appends KV in place;
             # decode_state/absorb_decode_state are the donation-aware pair
             # around this call.
-            self._step = donating_jit(_step, (3,), static_argnums=(9,))
+            self._step = donating_jit(_step, (3,), name="model_decode_step",
+                                      static_argnums=(9,))
         return self._step(self.dec_params, tokens, pos, state, block_tables,
                           temps, top_ks, top_ps, seeds, greedy_only)
 
@@ -835,6 +844,10 @@ class LocalDisaggEngine:
         self._c_autoscale = reg.counter(
             "engine_autoscale_decisions_total",
             "autoscaler resize decisions applied")
+        reg.counter_family(
+            "engine_program_traces_total",
+            "programs traced in this process, each one compiled or loaded "
+            "from the compile cache, by program", "program", program_traces)
         reg.gauge("engine_prefill_workers", "live prefill workers",
                   fn=lambda: len(self.prefill_workers))
         reg.gauge("engine_waiting_requests", "requests awaiting admission",
@@ -1036,8 +1049,8 @@ class LocalDisaggEngine:
         scheduler; plus once at construction): sequences are addressed by
         model id and every step re-derives lane indices from the new plane,
         so live sequences keep decoding bit-identically — their pages,
-        positions, and sampling keys are untouched by the remap. Trace and
-        dispatch counters carry across rebuilds (stats stay cumulative)."""
+        positions, and sampling keys are untouched by the remap. The
+        dispatch counter carries across rebuilds (stats stay cumulative)."""
         if not self.fused:
             return
         old = self.decode_plane
@@ -1045,7 +1058,6 @@ class LocalDisaggEngine:
             {mid: (self.cfg, spec)
              for mid, spec in self.models._specs.items()},
             self.kvpool, self.base_params,
-            traces0=old.traces if old is not None else 0,
             dispatches0=old.dispatches if old is not None else 0)
         if old is not None:
             self.stats.plane_rebuilds += 1
@@ -1387,7 +1399,8 @@ class LocalDisaggEngine:
         finishes mid-flight."""
         if not seqs:
             return
-        self._grow_tail_pages(seqs)
+        with TraceAnnotation(PS_DECODE_BUILD):
+            self._grow_tail_pages(seqs)
         if self.decode_plane is not None:
             before = self.decode_plane.dispatches
             nxt = self.decode_plane.step(seqs)
@@ -1399,40 +1412,41 @@ class LocalDisaggEngine:
                 by_model.setdefault(s.model_id, []).append(i)
             for mid, idx in by_model.items():
                 nxt[idx] = self._batched_step(mid, [seqs[i] for i in idx])
-        metrics_on = self._metrics_on      # ONE branch per token when off —
-        for i, s in enumerate(seqs):       # no metric objects touched at all
-            t = int(nxt[i])
-            s.out.append(t)
-            s.next_token = t
-            s.pos += 1
-            s.remaining -= 1
-            out = self._requests.get(s.rid)
-            if out is not None:
-                out._push(t)
-                if metrics_on:
-                    # TTFT/ITL histograms + trace spans use the SAME
-                    # timestamps RequestOutput just recorded at push time,
-                    # so exported percentiles are exactly what a streaming
-                    # client observes
-                    times = out.token_times
-                    if len(times) == 1:
-                        self._h_ttft.observe(times[0] - out.submit_time)
-                        self.metrics_registry.trace(s.rid).event(
-                            SPAN_FIRST_TOKEN, t=times[0])
-                    else:
-                        self._h_itl.observe(times[-1] - times[-2])
-                        self.metrics_registry.trace(s.rid).event(
-                            SPAN_TOKEN, t=times[-1])
-            reason = s.params.is_stop(t)
-            if reason is not None:
-                s.finish_reason = reason
-                s.remaining = 0                    # retired next reap
-        # one ENGINE step regardless of mode, so decode_steps (and
-        # decode_batch_mean) mean the same thing fused and legacy
-        self.stats.decode_steps += 1
-        self.stats.decode_tokens += len(seqs)
-        if metrics_on:
-            self._h_batch.observe(len(seqs))
+        with TraceAnnotation(PS_DECODE_PUSH):
+            metrics_on = self._metrics_on  # ONE branch per token when off —
+            for i, s in enumerate(seqs):   # no metric objects touched at all
+                t = int(nxt[i])
+                s.out.append(t)
+                s.next_token = t
+                s.pos += 1
+                s.remaining -= 1
+                out = self._requests.get(s.rid)
+                if out is not None:
+                    out._push(t)
+                    if metrics_on:
+                        # TTFT/ITL histograms + trace spans use the SAME
+                        # timestamps RequestOutput just recorded at push
+                        # time, so exported percentiles are exactly what a
+                        # streaming client observes
+                        times = out.token_times
+                        if len(times) == 1:
+                            self._h_ttft.observe(times[0] - out.submit_time)
+                            self.metrics_registry.trace(s.rid).event(
+                                SPAN_FIRST_TOKEN, t=times[0])
+                        else:
+                            self._h_itl.observe(times[-1] - times[-2])
+                            self.metrics_registry.trace(s.rid).event(
+                                SPAN_TOKEN, t=times[-1])
+                reason = s.params.is_stop(t)
+                if reason is not None:
+                    s.finish_reason = reason
+                    s.remaining = 0                # retired next reap
+            # one ENGINE step regardless of mode, so decode_steps (and
+            # decode_batch_mean) mean the same thing fused and legacy
+            self.stats.decode_steps += 1
+            self.stats.decode_tokens += len(seqs)
+            if metrics_on:
+                self._h_batch.observe(len(seqs))
 
     def _batched_step(self, mid: str, seqs: list[DecodeSeq]) -> np.ndarray:
         """One per-model jitted forward (legacy fused=False dispatch unit);

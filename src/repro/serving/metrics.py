@@ -47,6 +47,10 @@ __all__ = [
     "NullHistogram", "NullGauge", "NullTrace", "lint_prometheus",
     "SPAN_QUEUED", "SPAN_ROUTED", "SPAN_CHUNK", "SPAN_HANDOFF",
     "SPAN_FIRST_TOKEN", "SPAN_TOKEN", "SPAN_FINISHED", "SPAN_ABORTED",
+    "PS_STEP", "PS_SCHED_REAP", "PS_SCHED_SYNC", "PS_SCHED_ADMIT",
+    "PS_SCHED_PLAN", "PS_SCHED_PROMOTE", "PS_CHUNK_BUILD", "PS_CHUNK_WAIT",
+    "PS_DECODE_BUILD", "PS_DECODE_READBACK", "PS_DECODE_PUSH", "PS_RUN",
+    "PS_SPANS",
 ]
 
 # trace span-event names (one vocabulary, engine-wide)
@@ -61,6 +65,28 @@ SPAN_ABORTED = "aborted"
 
 #: terminal events — a trace is closed once it carries one of these
 _TERMINAL = (SPAN_FINISHED, SPAN_ABORTED)
+
+# profiler spans of the engine step (``jax.profiler.TraceAnnotation``): the
+# host's phases on the device trace's clock, so each stretch in which the
+# device idles inside a step is named by what the host was doing. While no
+# profiler records, a span costs about half a microsecond on a TPU v5e host.
+PS_STEP = "ps.step"                      # ChunkedScheduler.step, whole
+PS_SCHED_REAP = "ps.sched.reap"          # retire sequences: relay, unref
+PS_SCHED_SYNC = "ps.sched.sync"          # model churn, metrics, autoscale
+PS_SCHED_ADMIT = "ps.sched.admit"        # route, radix match, page alloc
+PS_SCHED_PLAN = "ps.sched.plan"          # oversubscription, chunk packing
+PS_SCHED_PROMOTE = "ps.sched.promote"    # commit, prefill -> decode handoff
+PS_CHUNK_BUILD = "ps.chunk.build"        # a chunk group's tables, its call
+PS_CHUNK_WAIT = "ps.chunk.wait"          # block until the group is done
+PS_DECODE_BUILD = "ps.decode.build"      # tail pages, tables, sampling
+PS_DECODE_READBACK = "ps.decode.readback"  # sampled tokens to the host
+PS_DECODE_PUSH = "ps.decode.push"        # stream, stop checks, events
+#: + a program's name (``repro.runtime.donating_jit``): its dispatch, which
+#: traces, lowers, compiles or loads, and enqueues it
+PS_RUN = "ps.run."
+PS_SPANS = (PS_STEP, PS_SCHED_REAP, PS_SCHED_SYNC, PS_SCHED_ADMIT,
+            PS_SCHED_PLAN, PS_SCHED_PROMOTE, PS_CHUNK_BUILD, PS_CHUNK_WAIT,
+            PS_DECODE_BUILD, PS_DECODE_READBACK, PS_DECODE_PUSH)
 
 
 def _fmt(v: float) -> str:
@@ -336,6 +362,7 @@ class MetricsRegistry:
         self._metrics: "OrderedDict[tuple, object]" = OrderedDict()
         self._traces: "OrderedDict[int, RequestTrace]" = OrderedDict()
         self.trace_capacity = trace_capacity
+        self._families: list = []       # (name, help, label, fn)
 
     # -- factories -------------------------------------------------------
     def _get(self, cls, name, help, labels, **kw):
@@ -377,6 +404,19 @@ class MetricsRegistry:
         return self._get(Histogram, name, help, self._labels(labels),
                          lo=lo, hi=hi, growth=growth)
 
+    def counter_family(self, name: str, help: str, label: str,
+                       fn) -> None:
+        """A counter whose series are read from ``fn() -> {label value:
+        count}`` at snapshot and exposition time: numbers kept outside the
+        registry (a process-wide table), with no hot-path writes."""
+        self._families.append((name, help, label, fn))
+
+    def _family_series(self):
+        for name, help_text, label, fn in self._families:
+            yield name, help_text, [
+                (name + _label_str(((label, str(v)),)), float(n))
+                for v, n in sorted(fn().items())]
+
     # -- traces ----------------------------------------------------------
     def start_trace(self, rid: int, model_id=None, t: float | None = None):
         if not self.enabled:
@@ -403,6 +443,8 @@ class MetricsRegistry:
         for (name, labels), m in self._metrics.items():
             key = name + _label_str(labels)
             out[m.kind + "s"][key] = m.snapshot()
+        for _name, _help, series in self._family_series():
+            out["counters"].update(series)
         return out
 
     def render_prometheus(self) -> str:
@@ -430,6 +472,11 @@ class MetricsRegistry:
                     lines.append(f"{name}_count{ls} {m.count}")
                 else:
                     lines.append(f"{name}{ls} {_fmt(m.snapshot())}")
+        for name, help_text, series in self._family_series():
+            if series:
+                lines.append(f"# HELP {name} {help_text or name}")
+                lines.append(f"# TYPE {name} counter")
+                lines += [f"{key} {_fmt(v)}" for key, v in series]
         return "\n".join(lines) + "\n"
 
 

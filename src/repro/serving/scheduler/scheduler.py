@@ -38,10 +38,15 @@ from dataclasses import dataclass, field
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.prefillshare import base_prefill_chunk
 from repro.kvcache.blocks import PoolExhausted
 from repro.serving.decode import next_pow2
+from repro.serving.metrics import (PS_CHUNK_BUILD, PS_CHUNK_WAIT,
+                                   PS_SCHED_ADMIT, PS_SCHED_PLAN,
+                                   PS_SCHED_PROMOTE, PS_SCHED_REAP,
+                                   PS_SCHED_SYNC, PS_STEP, SPAN_ROUTED)
 from repro.serving.scheduler.queue import POLICIES, order_requests
 
 
@@ -90,9 +95,11 @@ class Request:
     sibling_bt: list | None = None   # identical-context fast path block table
     resume_seq: object = None    # preempted DecodeSeq this request restores
                                  # (drop-and-recompute path, serving/preempt)
+    tok_array: np.ndarray = field(init=False, repr=False)  # tokens, numpy
 
     def __post_init__(self):
         self.tok_hash = hash(tuple(self.tokens))
+        self.tok_array = np.asarray(self.tokens, np.int32)
 
     @property
     def n(self) -> int:
@@ -148,48 +155,58 @@ class ChunkedScheduler:
         their budget slots and pages free BEFORE this step's packing); admit;
         pack prefill chunks under the budget; promote finished prefills
         (zero-copy handoff); advance every active sequence one decode
-        token."""
-        self.stats.steps += 1
-        progress = self._reap_finished()
-        # step boundary: apply deferred model churn (finalize drained
-        # unregisters — the reap above may have retired a draining model's
-        # last sequence — and relayout the fused plane; live sequences'
-        # lane indices are re-derived from the new plane this same step, so
-        # surviving requests decode bit-identically across the churn)
-        self.engine.models.sync()
-        # same boundary: observe queue depth / pool occupancy into the
-        # metrics registry, then let the autoscaler resize the prefill pool
-        # or the decode admission reserve off those signals — worker-set
-        # mutations are only legal here, exactly like model churn
-        self.engine._observe_step()
-        self.engine._autoscale_tick()
-        progress += self._admit()
-        progress += self._oversub_phase()
-        budget = self.cfg.token_budget - len(self.active)
-        chunks = self._plan_chunks(budget)
-        progress += self._run_chunks(chunks)
-        progress += self._promote()
-        progress += self._tail_growth_guard()
-        progress += self._decode_phase()
-        if self.engine.sanitizer is not None:
-            # step boundary: every transient ref/alloc has settled, so the
-            # pool/index/holder cross-check must hold exactly here
-            self.engine.sanitizer.check_step()
-        if progress == 0 and (self.waiting or self.prefilling
-                              or self._swap_parked()):
-            if self.engine.sched_reserve_extra > 0:
-                # the autoscaler's extra decode headroom is advisory — it
-                # must never wedge the engine. If it is the only thing
-                # blocking progress, give it back and retry next step.
-                self.engine.sched_reserve_extra = 0
-                return
-            swap = self.engine.swap
-            raise PoolExhausted(
-                f"scheduler stalled: {len(self.waiting)} waiting / "
-                f"{len(self.prefilling)} prefilling / "
-                f"{len(swap.records) if swap is not None else 0} swapped-out "
-                f"requests cannot obtain pages and no decode is active to "
-                f"free any")
+        token. Each phase runs inside its ``ps.*`` profiler span."""
+        with TraceAnnotation(PS_STEP):
+            self.stats.steps += 1
+            with TraceAnnotation(PS_SCHED_REAP):
+                progress = self._reap_finished()
+            with TraceAnnotation(PS_SCHED_SYNC):
+                # step boundary: apply deferred model churn (finalize
+                # drained unregisters — the reap above may have retired a
+                # draining model's last sequence — and relayout the fused
+                # plane; live sequences' lane indices are re-derived from
+                # the new plane this same step, so surviving requests
+                # decode bit-identically across the churn)
+                self.engine.models.sync()
+                # same boundary: observe queue depth / pool occupancy into
+                # the metrics registry, then let the autoscaler resize the
+                # prefill pool or the decode admission reserve off those
+                # signals — worker-set mutations are only legal here,
+                # exactly like model churn
+                self.engine._observe_step()
+                self.engine._autoscale_tick()
+            with TraceAnnotation(PS_SCHED_ADMIT):
+                progress += self._admit()
+            with TraceAnnotation(PS_SCHED_PLAN):
+                progress += self._oversub_phase()
+                budget = self.cfg.token_budget - len(self.active)
+                chunks = self._plan_chunks(budget)
+            progress += self._run_chunks(chunks)
+            with TraceAnnotation(PS_SCHED_PROMOTE):
+                progress += self._promote()
+            with TraceAnnotation(PS_SCHED_PLAN):
+                progress += self._tail_growth_guard()
+            progress += self._decode_phase()
+            if self.engine.sanitizer is not None:
+                # step boundary: every transient ref/alloc has settled, so
+                # the pool/index/holder cross-check must hold exactly here
+                self.engine.sanitizer.check_step()
+            if progress == 0 and (self.waiting or self.prefilling
+                                  or self._swap_parked()):
+                if self.engine.sched_reserve_extra > 0:
+                    # the autoscaler's extra decode headroom is advisory —
+                    # it must never wedge the engine. If it is the only
+                    # thing blocking progress, give it back and retry next
+                    # step.
+                    self.engine.sched_reserve_extra = 0
+                    return
+                swap = self.engine.swap
+                raise PoolExhausted(
+                    f"scheduler stalled: {len(self.waiting)} waiting / "
+                    f"{len(self.prefilling)} prefilling / "
+                    f"{len(swap.records) if swap is not None else 0} "
+                    f"swapped-out requests cannot obtain pages and no "
+                    f"decode is active to free any")
 
     # ---- oversubscription (serving/preempt.py) -------------------------
     def _oversub_phase(self) -> int:
@@ -229,10 +246,11 @@ class ChunkedScheduler:
             self.waiting.remove(r)
             w = self.engine._pick_worker(r.sid, r.tokens)
             r.worker = w
-            self.engine.metrics_registry.trace(r.rid).event(
-                "routed", worker=w.wid)
+            t_routed = time.perf_counter()
             sc = w.sessions.get(r.sid)
-            if sc is not None and sc.tokens == r.tokens:
+            sibling = sc is not None and sc.tokens == r.tokens
+            shared = 0 if sibling else self._in_flight_prefix(r)
+            if sibling:
                 # identical-context sibling: the session's pages already hold
                 # it — no allocation, no chunks, straight to promote. Pin the
                 # pages NOW (promotion may be deferred under pool pressure,
@@ -256,7 +274,27 @@ class ChunkedScheduler:
                 self.engine.stats.recompute_tokens += r.n - r.done
             self.prefilling.append(r)
             admitted += 1
+            # prompt tokens this request computes that another request in
+            # flight is computing too; the routed event's time is the pick
+            self.engine.stats.prefill_tokens_duplicated += max(
+                0, shared - r.done)
+            self.engine.metrics_registry.trace(r.rid).event(
+                SPAN_ROUTED, t=t_routed, worker=w.wid, cached=r.done)
         return admitted
+
+    def _in_flight_prefix(self, r: Request) -> int:
+        """Longest prefix ``r`` shares with an admitted request that has not
+        committed yet: tokens another request in flight is already
+        computing, which the radix tree cannot serve ``r`` until it
+        commits."""
+        best = 0
+        for p in self.prefilling:
+            if p.committed or p.sibling_bt is not None:
+                continue
+            n = min(r.n, p.n)
+            diff = np.flatnonzero(r.tok_array[:n] != p.tok_array[:n])
+            best = max(best, int(diff[0]) if diff.size else n)
+        return best
 
     # ---- prefill chunk packing ----------------------------------------
     def _plan_chunks(self, budget: int):
@@ -312,19 +350,23 @@ class ChunkedScheduler:
             # table growth WITHIN a bucket reuses the jitted chunk-step
             # trace, so prefill retraces stop scaling with prefix length
             # (padding = sentinel page 0, never live KV)
-            npages = next_pow2(max(len(r.block_table) for r, _ in items))
-            toks = np.zeros((B, S), np.int32)
-            bt = np.zeros((B, npages), np.int32)
-            pos = np.zeros((B,), np.int32)
-            for i, (r, start) in enumerate(items):
-                toks[i] = r.tokens[start:start + S]
-                bt[i, :len(r.block_table)] = r.block_table
-                pos[i] = start
-            t0 = time.perf_counter()
-            out = base_prefill_chunk(eng.cfg, eng.base_params, toks,
-                                     pool=eng.kvpool, block_tables=bt,
-                                     pos=pos)
-            jax.block_until_ready(out)
+            # the tables and their transfer are ps.chunk.build; the jitted
+            # call's dispatch inside it is ps.run.chunk_step
+            with TraceAnnotation(PS_CHUNK_BUILD):
+                npages = next_pow2(max(len(r.block_table) for r, _ in items))
+                toks = np.zeros((B, S), np.int32)
+                bt = np.zeros((B, npages), np.int32)
+                pos = np.zeros((B,), np.int32)
+                for i, (r, start) in enumerate(items):
+                    toks[i] = r.tokens[start:start + S]
+                    bt[i, :len(r.block_table)] = r.block_table
+                    pos[i] = start
+                t0 = time.perf_counter()
+                out = base_prefill_chunk(eng.cfg, eng.base_params, toks,
+                                         pool=eng.kvpool, block_tables=bt,
+                                         pos=pos)
+            with TraceAnnotation(PS_CHUNK_WAIT):
+                jax.block_until_ready(out)
             dt = time.perf_counter() - t0
             for r, _ in items:
                 r.done += S

@@ -9,8 +9,8 @@ import numpy as np
 import pytest
 
 from repro.configs.base import ModelConfig
-from repro.core.prefillshare import CHUNK_TRACES
 from repro.models import init_params
+from repro.runtime import program_traces
 from repro.serving.api import (FINISH_ABORT, FINISH_EOS, FINISH_LENGTH,
                                FINISH_STOP, SamplingParams)
 from repro.serving.engine import LocalDisaggEngine
@@ -543,11 +543,11 @@ def test_chunk_block_table_bucketing_bounds_retraces():
     decs = {"m0": init_params(cfg, jax.random.PRNGKey(10))}
     eng = LocalDisaggEngine(cfg, base, decs, num_pages=64, page_size=4,
                             chunked=True, chunk_size=8, token_budget=8)
-    before = CHUNK_TRACES.get(cfg, 0)
+    before = program_traces().get("chunk_step", 0)
     out = eng.generate("m0", _ctx(21, 96), SamplingParams(max_tokens=2))
     out.result()                               # 96 tokens -> 24 pages
     chunks = eng.scheduler.stats.chunks
-    traces = CHUNK_TRACES.get(cfg, 0) - before
+    traces = program_traces().get("chunk_step", 0) - before
     assert chunks >= 12                        # really was chunked
     # buckets hit: npages in {2,4,8,16,32} (+ the final ragged chunk S) —
     # far fewer traces than chunks; unbucketed tables would retrace ~every

@@ -9,6 +9,7 @@ import pytest
 from repro.configs.base import ATTN, ModelConfig
 from repro.kvcache.blocks import BlockPool
 from repro.models import init_params
+from repro.runtime import program_traces
 from repro.serving.decode import next_pow2
 from repro.serving.engine import LocalDisaggEngine
 
@@ -102,14 +103,15 @@ def test_npages_bucketing_stops_per_page_retraces():
     assert [next_pow2(n) for n in (1, 2, 3, 4, 5, 8, 9)] == [1, 2, 4, 4, 8, 8, 16]
     base, decs = _params(CFG, 1)
     eng = _engine(CFG, base, decs)
+    before = program_traces().get("decode_step", 0)
     # 23-token prompt -> 3 pages (bucket 4); 9 generated tokens end at
     # pos 32 -> 4 pages, still bucket 4: table growth must not retrace.
     eng.invoke(0, list(range(4, 4 + 23)), "m0", gen_tokens=9)
-    assert eng.decode_plane.traces == 1
+    assert program_traces()["decode_step"] - before == 1
     # push past 32 tokens -> 5 pages -> bucket 8: exactly one more trace
     eng.submit(0, list(range(4, 4 + 23)) + [5] * 6, "m0", gen_tokens=6)
     eng.run()
-    assert eng.decode_plane.traces == 2
+    assert program_traces()["decode_step"] - before == 2
 
 
 def test_pool_donation_pair_is_functional_off_tpu():

@@ -2,6 +2,7 @@
 trace-span ordering + abort paths, disabled-mode guarantees, and the
 Prometheus exposition + lint."""
 import math
+import time
 import tracemalloc
 
 import jax
@@ -305,3 +306,62 @@ def test_stats_surface_still_runs_on_registry_counters():
     snap = eng.metrics()["counters"]
     assert snap["engine_handoffs_total"] == eng.stats.handoffs > 0
     assert snap["engine_decode_tokens_total"] == eng.stats.decode_tokens > 0
+
+
+def test_routed_event_time_is_the_pick_and_carries_the_radix_hit():
+    """The ``routed`` event keeps the instant the router picked the worker
+    (before the radix match and page allocation) and carries ``cached``:
+    the prompt tokens the radix tree served, here a second request over
+    the first one's prefix."""
+    eng = _engine(chunked=True, chunk_size=8, token_budget=64)
+    marks = {}
+    pick, worker = eng._pick_worker, eng.prefill_workers[0]
+    begin = worker.mgr.begin
+
+    def timed_pick(*a, **kw):
+        w = pick(*a, **kw)
+        marks.setdefault("picked", []).append(time.perf_counter())
+        return w
+
+    def timed_begin(*a, **kw):
+        marks.setdefault("begin", []).append(time.perf_counter())
+        return begin(*a, **kw)
+
+    eng._pick_worker, worker.mgr.begin = timed_pick, timed_begin
+    rng = np.random.default_rng(8)
+    prefix = list(rng.integers(4, 60, size=37))
+    first = eng.generate("m0", prefix, SamplingParams(max_tokens=2))
+    eng.run()
+    reused = eng.stats.prefill_tokens_reused
+    second = eng.generate("m0", prefix + list(rng.integers(4, 60, size=9)),
+                          SamplingParams(max_tokens=2))
+    eng.run()
+    assert first.finished and second.finished
+    hit = eng.stats.prefill_tokens_reused - reused
+    assert hit == 32                        # whole 8-token pages of 37
+    for i, out in enumerate((first, second)):
+        (tr,) = [t for t in eng.metrics_registry.traces()
+                 if t.rid == out.request_id]
+        (ev,) = [(t, attrs) for n, t, attrs in tr.events
+                 if n == SPAN_ROUTED]
+        t, attrs = ev
+        assert marks["picked"][i] <= t <= marks["begin"][i]
+        assert attrs["cached"] == (0, hit)[i]
+
+
+def test_program_traces_exported_by_program():
+    """The process-wide per-program trace table shows in engine.stats(),
+    engine.metrics() and the Prometheus exposition, one series a program."""
+    eng = _engine(chunked=True, chunk_size=8, token_budget=64)
+    rng = np.random.default_rng(9)
+    eng.generate("m0", list(rng.integers(4, 60, size=12)),
+                 SamplingParams(max_tokens=2))
+    eng.run()
+    traces = eng.stats()["program_traces"]
+    assert traces["decode_step"] >= 1 and traces["chunk_step"] >= 1
+    key = 'engine_program_traces_total{program="decode_step"}'
+    assert eng.metrics()["counters"][key] == traces["decode_step"]
+    text = eng.render_prometheus()
+    assert lint_prometheus(text) == []
+    assert text.count("# TYPE engine_program_traces_total counter") == 1
+    assert f"{key} {traces['decode_step']}" in text

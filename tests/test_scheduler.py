@@ -9,6 +9,7 @@ import pytest
 from repro.configs.base import ModelConfig
 from repro.kvcache.blocks import PoolExhausted
 from repro.models import init_params
+from repro.serving.api import SamplingParams
 from repro.serving.engine import LocalDisaggEngine
 
 CFG = ModelConfig(name="sched-eng", arch_type="dense", n_layers=2, d_model=32,
@@ -242,3 +243,29 @@ def test_equal_length_chunks_batch_into_one_forward():
     for rid, want in zip(rids, wants):
         np.testing.assert_array_equal(eng.result(rid), want)
     assert eng.scheduler.stats.max_prefill_batch >= 2
+
+
+def test_duplicate_prefill_counted_once_per_shared_document():
+    """Two fan-out requests over one 1,000-token document, admitted in the
+    same step: neither has committed, so the radix tree serves the second
+    nothing and it computes the document again. The document's tokens count
+    once as duplicated; a third request admitted after both committed
+    counts none."""
+    base, decs = _params()
+    eng = _engine(base, decs, num_pages=512, chunked=True, chunk_size=64,
+                  token_budget=256)
+    rng = np.random.default_rng(11)
+    doc = list(rng.integers(4, 60, size=1000))
+    a = eng.generate("m0", doc + [61, 62], SamplingParams(max_tokens=2))
+    b = eng.generate("m1", doc + [62, 61], SamplingParams(max_tokens=2))
+    eng.step()
+    assert eng.stats.prefill_tokens_reused == 0
+    assert eng.stats.prefill_tokens_duplicated == 1000
+    eng.run()
+    assert a.finished and b.finished
+    assert eng.stats.prefill_tokens_computed == 2 * 1002
+    c = eng.generate("m0", doc + [63], SamplingParams(max_tokens=2))
+    eng.run()
+    assert c.finished
+    assert eng.stats.prefill_tokens_duplicated == 1000
+    assert eng.stats()["prefill_tokens_duplicated"] == 1000
