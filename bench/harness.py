@@ -29,6 +29,7 @@ import jax
 import numpy as np
 
 from bench import model as bmodel
+from bench import tracefile
 from bench import traffic as btraffic
 from bench import warm as bwarm
 from bench.peaks import peaks
@@ -145,7 +146,8 @@ def _stats(engine) -> dict:
     return {"computed": st.prefill_tokens_computed,
             "reused": st.prefill_tokens_reused,
             "decode_tokens": st.decode_tokens,
-            "decode_steps": st.decode_steps}
+            "decode_steps": st.decode_steps,
+            "duplicated": getattr(st, "prefill_tokens_duplicated", None)}
 
 
 def _record(r: Request) -> RequestRecord:
@@ -401,7 +403,9 @@ class Deployment:
             unsent=sum(len(self.modules) for d in drv.due if d[0] < t_close),
             warm=self.warm, programs_setup=c_open,
             compile_s=counter.compile_s,
-            decode_steps=stats_close["decode_steps"] - stats_open["decode_steps"])
+            decode_steps=stats_close["decode_steps"] - stats_open["decode_steps"],
+            host_split=(None if view is None else tracefile.host_split(
+                view.rec, view.t0_ns, view.t1_ns)))
         return run
 
     def close(self) -> None:
@@ -446,7 +450,6 @@ def _trace_view(trace_dir: str, perf_open: float):
     import glob
     import shutil
 
-    from bench import tracefile
     paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                       recursive=True)
     if not paths:

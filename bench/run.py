@@ -134,6 +134,8 @@ def main(argv=None) -> int:
     out = result(run, checks, cell, bool(args.trace), devices)
     if args.dump:
         dump(run, checks, out, args.dump)
+    print(f"bench: notes {json.dumps(run.notes, default=str)}",
+          file=sys.stderr)
     for name, c in out["checks"].items():
         print(f"check {name}: {c['value']} limit {c['limit']}",
               file=sys.stderr)

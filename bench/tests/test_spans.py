@@ -1,7 +1,7 @@
 """The split of the device's idle time inside ``engine.step`` by the
 program's own host spans: on hand-made intervals, on a trace recorded on a
 TPU v5e with the program's ``ps.*`` spans in it, on the older trace that has
-none, and through ``bench/spans.py`` on the CPU at a tiny size."""
+none, and through a traced ``bench/run.py`` on the CPU at a tiny size."""
 import json
 import os
 import shutil
@@ -11,7 +11,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import spans, tracefile
+from bench import tracefile
 from bench.harness import TraceView
 from bench.spec import reader
 
@@ -57,10 +57,10 @@ def test_innermost_span_names_each_idle_nanosecond():
                     ["ps.step", 0, 100], ["ps.run.decode_step", 10, 40],
                     ["backend_compile_and_load", 20, 20],
                     ["ps.decode.push", 90, 0]]}
-    assert spans.idle_by_span(rec, 0, 100) == {
+    assert tracefile.idle_by_span(rec, 0, 100) == {
         "ps.step": 10 + 10 + 30, "ps.run.decode_step": 10 + 10,
         "backend_compile_and_load": 20}
-    split = spans.host_split(rec, 0, 100)
+    split = tracefile.host_split(rec, 0, 100)
     assert split["host.compile_idle_share"] == 20.0
     assert split["host.dispatch_idle_share"] == 20.0
     assert split["rest"] == 50.0
@@ -69,14 +69,14 @@ def test_innermost_span_names_each_idle_nanosecond():
 
 def test_shares_and_rest_are_the_idle_inside_steps(traced):
     rec, t0, t1 = traced
-    by = spans.idle_by_span(rec, t0, t1)
+    by = tracefile.idle_by_span(rec, t0, t1)
     want = _idle_inside_steps(rec, t0, t1)
     assert 0 < want and sum(by.values()) == want
     names = set(by)
     assert names <= ({n for n, *_ in rec["host"] if n.startswith("ps.")}
-                     | set(spans.JAX_COMPILE_EVENTS) | {"none"})
-    split = spans.host_split(rec, t0, t1)
-    shares = [split[g] for g in spans.GROUPS] + [split["rest"]]
+                     | set(tracefile.JAX_COMPILE_EVENTS) | {"none"})
+    split = tracefile.host_split(rec, t0, t1)
+    shares = [split[g] for g in tracefile.HOST_GROUPS] + [split["rest"]]
     assert sum(shares) == pytest.approx(100.0 * want / split["inside_ns"],
                                         rel=1e-12)
     # a trace of the program's step names its idle by the step's phases
@@ -86,9 +86,9 @@ def test_shares_and_rest_are_the_idle_inside_steps(traced):
 
 def test_trace_without_program_spans_reads_all_idle_as_rest():
     rec, t0, t1 = _load("trace_v5e.json.gz")
-    split = spans.host_split(rec, t0, t1)
-    assert [split[g] for g in spans.GROUPS] == [0.0] * len(spans.GROUPS)
-    assert split["idle_ns"] == _idle_inside_steps(rec, t0, t1) > 0
+    want = _idle_inside_steps(rec, t0, t1)
+    assert tracefile.idle_by_span(rec, t0, t1) == {"none": want} and want > 0
+    assert tracefile.host_split(rec, t0, t1) is None
 
 
 def test_program_spans_leave_the_trace_readers_as_they_were(traced):
@@ -110,7 +110,78 @@ def test_program_spans_leave_the_trace_readers_as_they_were(traced):
             == sum(ns for _, ns in tracefile.idle_gaps(bare, t0, t1)))
 
 
-def test_spans_command_splits_a_tiny_cell_on_the_cpu(tmp_path):
+class _TracedRun:
+    def __init__(self, rec, t0, t1):
+        self.trace = TraceView(rec=rec, t0_ns=t0, t1_ns=t1, perf_open=0.0)
+        self.notes = {"host_split": tracefile.host_split(rec, t0, t1)}
+
+
+def test_host_share_readers_and_the_rest_are_the_idle_inside_steps(traced):
+    rec, t0, t1 = traced
+    run = _TracedRun(rec, t0, t1)
+    shares = [reader(g)(run) for g in tracefile.HOST_GROUPS]
+    split = run.notes["host_split"]
+    assert shares == [split[g] for g in tracefile.HOST_GROUPS]
+    want = 100.0 * _idle_inside_steps(rec, t0, t1) / split["inside_ns"]
+    assert sum(shares) + split["rest"] == pytest.approx(want, rel=1e-12)
+    # every operation of this window ran inside a step, so
+    # ``device.idle_share`` reads the same idle
+    assert reader("device.idle_share")(run) == pytest.approx(want, abs=0.3)
+    assert all(v >= 0 for v in shares)
+    counts = split["span_counts"]
+    assert counts["ps.step"] > 0
+    assert all(n.startswith(tracefile.PROGRAM_PREFIX) for n in counts)
+
+
+@pytest.mark.parametrize("name", list(tracefile.HOST_GROUPS))
+def test_host_share_readers_read_nothing_without_program_spans(name):
+    rec, t0, t1 = _load("trace_v5e.json.gz")
+    assert reader(name)(_TracedRun(rec, t0, t1)) is None
+
+    class Untraced:
+        trace, notes = None, {}
+    assert reader(name)(Untraced()) is None
+
+
+@pytest.mark.parametrize("before,after,want", [
+    ({"computed": 100, "duplicated": 10}, {"computed": 500, "duplicated": 210},
+     50.0),
+    ({"computed": 100, "duplicated": 10}, {"computed": 100, "duplicated": 10},
+     None),
+    ({"computed": 100, "duplicated": None},
+     {"computed": 500, "duplicated": None}, None),
+])
+def test_duplicate_prefill_share_over_the_window(before, after, want):
+    class Counted:
+        stats_open, stats_close = before, after
+    assert reader("sched.duplicate_prefill_share")(Counted()) == want
+
+
+def test_extract_keeps_program_spans_and_compile_events(tmp_path):
+    """A trace taken here keeps the benchmark's and the program's spans and
+    JAX's compile events, and no other host event."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("bench.window"):
+            with jax.profiler.TraceAnnotation("ps.run.probe"):
+                jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
+            with jax.profiler.TraceAnnotation("other.span"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e[0] for e in tracefile.extract(path)["host"]}
+    assert {"bench.window", "ps.run.probe"} <= names
+    assert names & set(tracefile.JAX_COMPILE_EVENTS)
+    assert all(n.startswith(tracefile.KEPT_HOST) for n in names)
+    assert "other.span" not in names
+
+
+def test_traced_rehearsal_splits_a_tiny_cell_on_the_cpu(tmp_path):
     shutil.copytree(ROOT / "bench", tmp_path / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     os.symlink(ROOT / "src", tmp_path / "src")
@@ -126,19 +197,24 @@ def test_spans_command_splits_a_tiny_cell_on_the_cpu(tmp_path):
                             "traffic": "tiny-fanout", "chips": 1,
                             "why": "test"})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bm))
+    dump = tmp_path / "dump"
     p = subprocess.run(
-        [sys.executable, "bench/spans.py", "--workload", "tiny.tiny-fanout",
-         "--seed", "2147483701", "--seconds", "2", "--rehearse"],
+        [sys.executable, "bench/run.py", "--workload", "tiny.tiny-fanout",
+         "--seed", "2147483701", "--seconds", "2", "--trace", "1",
+         "--rehearse", "--dump", str(dump)],
         cwd=tmp_path, capture_output=True, text=True, timeout=900,
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert p.returncode == 3, p.stderr[-3000:]
-    out = json.loads(p.stdout.strip().splitlines()[-1])
-    split = out["split"]
+    summary = json.loads((dump / "summary.json").read_text())
+    split = summary["notes"]["host_split"]
     assert split["idle_ns"] > 0
-    assert sum(split[g] for g in spans.GROUPS) + split["rest"] == \
+    shares = [split[g] for g in tracefile.HOST_GROUPS]
+    assert sum(shares) + split["rest"] == \
         pytest.approx(100.0 * split["idle_ns"] / split["inside_ns"])
-    assert out["span_counts"]["ps.step"] > 0
-    assert out["duplicate_prefill_share"] > 0
+    assert split["span_counts"]["ps.step"] > 0
+    metrics = summary["result"]["metrics"]
+    assert [metrics[g]["value"] for g in tracefile.HOST_GROUPS] == shares
+    assert metrics["sched.duplicate_prefill_share"]["value"] > 0
 
 
 def test_step_programs_carry_their_names(traced):
