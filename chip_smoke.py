@@ -10,7 +10,8 @@ random weights, over one shared paged KV pool of 1024 pages of 16 tokens.
 Phases:
   (c) each Pallas kernel of the main path (paged_decode,
       flash_prefill_paged, paged_write) against its ``kernels/ref.py`` twin
-      at qwen3-1.7b widths, with a stated error bound;
+      at qwen3-1.7b widths, with a stated error bound; paged_decode also
+      over a 1024-page table whose rows end at 1, 17, 12,000 and 16,384;
   (a) the default engine (eager prefill): one shared context of 2048
       tokens, each decode module generates 32 greedy tokens; the context is
       extended by 256 tokens and both modules fan out again, the second turn
@@ -140,6 +141,15 @@ def check_kernels(cfg):
     o = jax.jit(paged_decode_attention)(q, kp, vp, bt, lengths, layer=1)
     r = ref_paged_decode(q, kp, vp, bt, lengths, layer=1)
     report("paged_decode", max_err(o, r), KERNEL_BOUND)
+
+    # a 1024-page table (the 8b cell's bucket) over the same pool: a
+    # padding row, a row ending inside its first page, one inside a block
+    # and one filling the whole table
+    wide = jax.random.randint(ks[2], (4, 1024), 1, pool)
+    lengths = jnp.asarray([1, 17, 12_000, 16_384], jnp.int32)
+    o = jax.jit(paged_decode_attention)(q, kp, vp, wide, lengths, layer=1)
+    r = ref_paged_decode(q, kp, vp, wide, lengths, layer=1)
+    report("paged_decode 1024-page table", max_err(o, r), KERNEL_BOUND)
 
     s = CHUNK
     qc = jax.random.normal(ks[5], (2, s, hq, d), dt)

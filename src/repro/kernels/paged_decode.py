@@ -9,16 +9,23 @@ Pool layout: ``(P, page, Hkv * D)`` — the heads of one token row are
 contiguous — optionally stacked on a leading layer axis ``(L, P, page,
 Hkv * D)`` and read at ``layer``, so a layer-scanned model step hands the
 kernel the whole stacked pool instead of slicing (copying) one layer out of
-it. One kv head's rows of one page are the ``(page, D)`` block at lane
-offset ``h * D``: a legal TPU block (``page`` a multiple of 8, ``D`` of 128)
-that a strided DMA fetches straight from HBM.
+it. One page of one layer is thus a contiguous ``(page, Hkv * D)`` slab,
+every kv head at once.
 
-The layer index, block table and sequence lengths ride in scalar-prefetch
-(SMEM) via ``PrefetchScalarGridSpec``, so the K/V BlockSpec index maps
-dereference the page table while the previous page streams HBM->VMEM. Grid
-iterates (batch, kv_head, page); the full GQA query group for a kv head is
-processed together (q block (group, D)), amortizing each K/V page fetch
-across the group.
+The grid is one step per decode row. The pools stay in HBM; the step walks
+the row's pages in blocks of ``pages_per_block`` whole pages, one async copy
+per page slab into a double-buffered VMEM block, and starts the copies of
+the next block (or of the next row's first block) before it computes the
+current one. Each kv head reads its ``D`` lanes of the block against its
+whole GQA query group, with the online softmax (f32 scores, max, sum and
+accumulator) carried per head across blocks.
+
+What it skips: a row walks ``cdiv(length, block)`` blocks, not the table's
+(bucketed) width, and copies only its live pages, so table columns past the
+row's length cost neither a copy nor a grid step; a padding row (length 1)
+costs one page. Only the last block masks keys past the length, and zeroes
+its value rows (the unfetched part of the block holds stale VMEM). The
+layer index, block tables and lengths ride in scalar prefetch (SMEM).
 """
 from __future__ import annotations
 
@@ -30,50 +37,109 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+#: most keys one block covers, and the VMEM its double-buffered K and V
+#: slabs may take together
+BLOCK_TOKENS = 512
+BUFFER_BYTES = 4 << 20
 
 
-def _kernel(layer_ref, block_tables_ref, lengths_ref, q_ref, k_ref, v_ref,
-            o_ref, m_scr, l_scr, acc_scr, *, scale: float, softcap: float,
-            page: int, npages: int, group: int):
-    del layer_ref, block_tables_ref         # consumed by the index maps
+def pages_per_block(page: int, width: int, itemsize: int,
+                    npages: int) -> int:
+    """Whole pages per block, from the static shapes: at most
+    ``BLOCK_TOKENS`` keys, two slots of K and V within ``BUFFER_BYTES``, and
+    no wider than the table."""
+    by_tokens = BLOCK_TOKENS // page
+    by_vmem = BUFFER_BYTES // (4 * page * width * itemsize)
+    return max(1, min(by_tokens, by_vmem, npages))
+
+
+def _kernel(layer_ref, tables_ref, lengths_ref, q_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sems, slot_ref, *, scale: float, softcap: float,
+            page: int, ppb: int, npages: int, hkv: int, head_dim: int,
+            rows: int):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    block = ppb * page
+    layer = layer_ref[0]
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def copies(row, blk, slot, action):
+        """Start or wait the copies of block ``blk`` of ``row``: each live
+        page's K and V slab into ``slot``."""
+        first = blk * ppb
+        live = (lengths_ref[row] + page - 1) // page
+        n = jnp.clip(live - first, 0, ppb)
+
+        def one(i, carry):
+            pg = tables_ref[row * npages + first + i]
+            dst = pl.ds(pl.multiple_of(i * page, page), page)
+            for s, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                cp = pltpu.make_async_copy(hbm.at[layer, pg],
+                                           buf.at[slot, dst], sems.at[s, slot])
+                getattr(cp, action)()
+            return carry
+        jax.lax.fori_loop(0, n, one, 0)
+
+    @pl.when(b == 0)
+    def _prime():
+        copies(0, 0, 0, "start")
+        slot_ref[0] = 0
 
     length = lengths_ref[b]
-    live = j * page < length
+    s0 = slot_ref[0]
+    nblk = jnp.maximum((length + block - 1) // block, 1)
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (group, D)
-        k = k_ref[0, 0].astype(jnp.float32)                  # (page, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if softcap:
-            s = jnp.tanh(s / softcap) * softcap
-        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (group, page), 1)
-        mask = kpos < length
-        s = jnp.where(mask, s, NEG)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new) * mask
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
-        v = v_ref[0, 0].astype(jnp.float32)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+    def attend(slot, blk, carry, masked):
+        first = blk * block
+        live = first + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+        live_rows = first + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+        live, live_rows = live < length, live_rows < length
+        out = []
+        for h, (m, l, acc) in enumerate(carry):
+            lanes = pl.ds(h * head_dim, head_dim)
+            q = q_ref[0, h]                                   # (group, D)
+            k = k_buf[slot, :, lanes]                         # (block, D)
+            # bf16 x bf16 products are exact in f32: f32 scores as before
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * scale
+            if softcap:
+                s = jnp.tanh(s / softcap) * softcap
+            v = v_buf[slot, :, lanes]
+            if masked:
+                s = jnp.where(live, s, NEG)
+                v = jnp.where(live_rows, v, 0)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+            l = l * corr + p.sum(axis=-1, keepdims=True)
+            acc = acc * corr + jax.lax.dot_general(
+                p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            out.append((m_new, l, acc))
+        return out
 
-    @pl.when(j == npages - 1)
-    def _final():
-        o_ref[0, 0] = (acc_scr[...] /
-                       jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+    def body(blk, carry):
+        slot = (s0 + blk) % 2
+        copies(b, blk + 1, 1 - slot, "start")
+        copies(b, blk, slot, "wait")
+        return attend(slot, blk, carry, masked=False)
+
+    group = q_ref.shape[2]
+    init = [(jnp.full((group, 1), NEG, jnp.float32),
+             jnp.zeros((group, 1), jnp.float32),
+             jnp.zeros((group, head_dim), jnp.float32))] * hkv
+    carry = jax.lax.fori_loop(0, nblk - 1, body, init)
+
+    last = nblk - 1
+    slot = (s0 + last) % 2
+
+    @pl.when(b + 1 < rows)
+    def _next_row():
+        copies(b + 1, 0, 1 - slot, "start")
+    slot_ref[0] = 1 - slot
+
+    copies(b, last, slot, "wait")
+    for h, (_, l, acc) in enumerate(attend(slot, last, carry, masked=True)):
+        o_ref[0, h] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def as_layer_stack(k_pages, v_pages, layer):
@@ -85,6 +151,9 @@ def as_layer_stack(k_pages, v_pages, layer):
     return k_pages, v_pages, jnp.asarray(layer, jnp.int32).reshape(1)
 
 
+# jitted, so each shape's kernel is traced once per process: a decode step
+# program calls it once per lane, and set-up builds many such programs
+@functools.partial(jax.jit, static_argnames=("softcap", "scale", "interpret"))
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
                            layer=None, softcap: float = 0.0,
                            scale: float | None = None,
@@ -96,52 +165,54 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
                   layer-stacked (L, P, page_size, Hkv * D) pool
     v_pages:      same layout as ``k_pages``
     block_tables: (B, npages) int32 logical->physical page ids
-    lengths:      (B,) int32 valid KV length per sequence
+    lengths:      (B,) int32 valid KV length per sequence, at most
+                  ``npages * page_size``
     layer:        int32 scalar: the layer a stacked pool is read at
     returns       (B, Hq, D)
     """
     B, Hq, D = q.shape
     k_pages, v_pages, layer = as_layer_stack(k_pages, v_pages, layer)
-    page = k_pages.shape[2]
-    Hkv = k_pages.shape[3] // D
+    page, width = k_pages.shape[2], k_pages.shape[3]
+    hkv = width // D
     npages = block_tables.shape[1]
-    group = Hq // Hkv
+    group = Hq // hkv
     scale = D ** -0.5 if scale is None else scale
+    ppb = pages_per_block(page, width, k_pages.dtype.itemsize, npages)
 
     kernel = functools.partial(_kernel, scale=scale, softcap=softcap,
-                               page=page, npages=npages, group=group)
+                               page=page, ppb=ppb, npages=npages, hkv=hkv,
+                               head_dim=D, rows=B)
 
-    def q_map(b, h, j, ly, bt, ln):
-        return (b, h, 0, 0)
-
-    def page_map(b, h, j, ly, bt, ln):
-        return (ly[0], bt[b, j], 0, h)
+    def row_map(b, *_):
+        return (b, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, Hkv, npages),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, 1, group, D), q_map),   # whole group for (b, h)
-            # head h's rows of the page the block table names
-            pl.BlockSpec((1, 1, page, D), page_map),
-            pl.BlockSpec((1, 1, page, D), page_map),
+            pl.BlockSpec((1, hkv, group, D), row_map),   # the row's heads
+            pl.BlockSpec(memory_space=pl.ANY),            # pools stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, D), q_map),
+        out_specs=pl.BlockSpec((1, hkv, group, D), row_map),
         scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, D), jnp.float32),
+            pltpu.VMEM((2, ppb * page, width), k_pages.dtype),
+            pltpu.VMEM((2, ppb * page, width), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),              # (K/V, slot)
+            pltpu.SMEM((1,), jnp.int32),                  # slot of row b's block 0
         ],
     )
 
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, hkv, group, D), q.dtype),
+        # the next row's first block is fetched by this step: sequential
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode",
-    )(layer, block_tables, lengths, q.reshape(B, Hkv, group, D),
+    )(layer, block_tables.reshape(-1).astype(jnp.int32),
+      lengths.astype(jnp.int32), q.reshape(B, hkv, group, D),
       k_pages, v_pages)
     return out.reshape(B, Hq, D)

@@ -121,6 +121,10 @@ class StackedDecoders:
         else:
             self.stacked = [s.full for s in specs]
         self.dispatches = 0                          # jitted-step invocations
+        # block-table pages the step hands paged_decode (lanes x rows x
+        # bucketed width) and the pages its real rows attend to
+        self.table_pages = 0
+        self.live_pages = 0
         self._step = self._build_step()
 
     def param_bytes(self) -> int:
@@ -193,6 +197,9 @@ class StackedDecoders:
                 counts[m] += 1
             bmax = max(counts)
             npages = next_pow2(max(len(s.block_table) for s in seqs))
+            page = self.kvpool.page_size
+            self.table_pages += M * bmax * npages
+            self.live_pages += sum(s.pos // page + 1 for s in seqs)
             toks = np.zeros((M, bmax), np.int32)
             pos = np.zeros((M, bmax), np.int32)
             bts = np.zeros((M, bmax, npages), np.int32)  # pad: sentinel 0
@@ -239,6 +246,14 @@ class FusedDecodePlane:
     @property
     def dispatches(self) -> int:
         return self._dispatches0 + sum(g.dispatches for g in self.groups)
+
+    @property
+    def table_pages(self) -> int:
+        return sum(g.table_pages for g in self.groups)
+
+    @property
+    def live_pages(self) -> int:
+        return sum(g.live_pages for g in self.groups)
 
     def param_bytes(self) -> int:
         """Decode-plane weight bytes beyond the engine's single base copy

@@ -232,6 +232,12 @@ class EngineStats:
         "engine_decode_tokens_total", "tokens generated across all sequences")
     decode_dispatches = _CounterField(
         "engine_decode_dispatches_total", "jitted decode forwards issued")
+    decode_table_pages = _CounterField(
+        "engine_decode_table_pages_total",
+        "block-table pages handed to decode attention: rows x bucketed width")
+    decode_live_pages = _CounterField(
+        "engine_decode_live_pages_total",
+        "pages decode attention reads: ceil(length / page) per real row")
     model_churn_events = _CounterField(
         "engine_model_churn_events_total",
         "accepted register/unregister mutations")
@@ -266,7 +272,8 @@ class EngineStats:
     FIELDS = ("prefill_tokens_computed", "prefill_tokens_reused",
               "prefill_tokens_duplicated", "handoffs",
               "handoff_bytes", "cow_page_copies", "decode_steps",
-              "decode_tokens", "decode_dispatches", "model_churn_events",
+              "decode_tokens", "decode_dispatches", "decode_table_pages",
+              "decode_live_pages", "model_churn_events",
               "plane_rebuilds", "relay_publishes", "relay_pages_published",
               "relay_skipped", "preemptions", "swap_out_pages",
               "swap_in_pages", "recompute_tokens", "swap_bytes")
@@ -1402,9 +1409,12 @@ class LocalDisaggEngine:
         with TraceAnnotation(PS_DECODE_BUILD):
             self._grow_tail_pages(seqs)
         if self.decode_plane is not None:
-            before = self.decode_plane.dispatches
-            nxt = self.decode_plane.step(seqs)
-            self.stats.decode_dispatches += self.decode_plane.dispatches - before
+            plane, st = self.decode_plane, self.stats
+            before = plane.dispatches, plane.table_pages, plane.live_pages
+            nxt = plane.step(seqs)
+            st.decode_dispatches += plane.dispatches - before[0]
+            st.decode_table_pages += plane.table_pages - before[1]
+            st.decode_live_pages += plane.live_pages - before[2]
         else:
             nxt = np.zeros(len(seqs), np.int32)
             by_model: dict[str, list] = {}
@@ -1456,6 +1466,9 @@ class LocalDisaggEngine:
         # pow2-bucket the table width: the padded columns are masked by pos,
         # so this is token-identical while bounding jit retraces at O(log)
         npages = next_pow2(max(len(s.block_table) for s in seqs))
+        self.stats.decode_table_pages += len(seqs) * npages
+        self.stats.decode_live_pages += sum(s.pos // self.page_size + 1
+                                            for s in seqs)
         bt = np.zeros((len(seqs), npages), np.int32)
         for i, s in enumerate(seqs):
             bt[i, :len(s.block_table)] = s.block_table

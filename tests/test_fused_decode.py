@@ -2,6 +2,9 @@
 engine step for ALL decode models, bit-identical greedy tokens vs
 the per-model dispatch loop, donation-aware pool updates, power-of-two
 block-table bucketing, and the page-0 padding sentinel."""
+import math
+from collections import Counter
+
 import jax
 import numpy as np
 import pytest
@@ -10,8 +13,9 @@ from repro.configs.base import ATTN, ModelConfig
 from repro.kvcache.blocks import BlockPool
 from repro.models import init_params
 from repro.runtime import program_traces
-from repro.serving.decode import next_pow2
+from repro.serving.decode import StackedDecoders, next_pow2
 from repro.serving.engine import LocalDisaggEngine
+from repro.serving.metrics import lint_prometheus
 
 CFG = ModelConfig(name="fused-eng", arch_type="dense", n_layers=2, d_model=32,
                   n_heads=2, n_kv_heads=1, d_ff=64, vocab_size=64,
@@ -198,3 +202,56 @@ def test_result_fetch_states():
         eng.pop_result(rid)
     with pytest.raises(KeyError, match="unknown request id"):
         eng.result(999)
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "per-model"])
+def test_decode_page_counters_match_block_tables(fused, monkeypatch):
+    """decode_table_pages counts what each decode dispatch hands the
+    attention kernel (lanes x rows x bucketed table width), and
+    decode_live_pages the pages its real rows attend to: both equal a hand
+    count from the block tables, and both are exported."""
+    base, decs = _params(CFG, 2)
+    eng = _engine(CFG, base, decs, fused=fused)
+    calls = []          # per dispatch: (lanes, [(model, pos, table pages)])
+
+    def record(lanes, seqs):
+        calls.append((lanes, [(s.model_id, s.pos, len(s.block_table))
+                              for s in seqs]))
+
+    if fused:
+        step = StackedDecoders.step
+
+        def spy(self, seqs):
+            record(len(self.model_ids), seqs)
+            return step(self, seqs)
+        monkeypatch.setattr(StackedDecoders, "step", spy)
+    else:
+        batched = eng._batched_step
+
+        def spy(mid, seqs):
+            record(1, seqs)
+            return batched(mid, seqs)
+        monkeypatch.setattr(eng, "_batched_step", spy)
+
+    rng = np.random.default_rng(3)
+    for sid, n in enumerate((13, 37)):       # unequal prompts, one a module
+        eng.submit(sid, list(rng.integers(4, 60, size=n)), f"m{sid}",
+                   gen_tokens=6)
+    eng.run()
+
+    table = live = 0
+    for lanes, rows in calls:
+        rows_per_lane = max(Counter(m for m, _, _ in rows).values())
+        width = 1
+        while width < max(n for _, _, n in rows):
+            width *= 2
+        table += lanes * rows_per_lane * width
+        live += sum(math.ceil((pos + 1) / PAGE) for _, pos, _ in rows)
+    stats = eng.stats()
+    assert calls and 0 < live < table
+    assert stats["decode_table_pages"] == table
+    assert stats["decode_live_pages"] == live
+    text = eng.render_prometheus()
+    assert lint_prometheus(text) == []
+    assert f"engine_decode_table_pages_total {table}" in text
+    assert f"engine_decode_live_pages_total {live}" in text

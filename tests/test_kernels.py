@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.ops import flash_attention, paged_attention
+from repro.kernels.paged_decode import paged_decode_attention
 from repro.kernels.ref import ref_flash_prefill, ref_paged_decode
 
 KEY = jax.random.PRNGKey(42)
@@ -45,27 +46,45 @@ def test_flash_prefill_vs_ref(case, dtype):
 
 
 PAGED_CASES = [
-    # B, Hq, Hkv, D, page, npages, pool
-    (3, 8, 2, 64, 16, 8, 40),
-    (1, 4, 4, 128, 32, 4, 16),
-    (2, 8, 1, 64, 16, 16, 64),    # MQA long table
-    (4, 2, 2, 32, 8, 4, 20),
+    # B, Hq, Hkv, D, page, npages, pool, lengths (None: random), layers
+    # (None: one layer's pool; else a stack read at its last layer)
+    (3, 8, 2, 64, 16, 8, 40, None, None),
+    (1, 4, 4, 128, 32, 4, 16, None, None),
+    (2, 8, 1, 64, 16, 16, 64, None, None),    # MQA long table
+    (4, 2, 2, 32, 8, 4, 20, None, None),
+    # blocks of 32 pages over a 48-page table (not a multiple of the
+    # block): a row ending mid-block, one ending on the block boundary, a
+    # padding row of length 1, one filling the whole table
+    (4, 8, 2, 64, 16, 48, 120, (600, 512, 1, 768), None),
+    # a bucketed table far wider than the live pages (a dead tail)
+    (2, 4, 2, 64, 8, 256, 40, (20, 70), None),
+    # a layer-stacked pool read at layer 2
+    (3, 8, 2, 64, 16, 40, 64, (33, 513, 640), 3),
+    # qwen3-1.7b and qwen3-8b heads: (Hq, Hkv, D, page) = (16|32, 8, 128, 16)
+    (3, 16, 8, 128, 16, 64, 80, (1, 512, 1000), 2),
+    (3, 32, 8, 128, 16, 64, 80, (17, 700, 1024), None),
 ]
 
 
 @pytest.mark.parametrize("case", PAGED_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_vs_ref(case, dtype):
-    B, Hq, Hkv, D, page, npages, P = case
+    B, Hq, Hkv, D, page, npages, P, lengths, layers = case
     ks = jax.random.split(KEY, 5)
+    pool = (P, page, Hkv * D) if layers is None else (layers, P, page, Hkv * D)
+    layer = None if layers is None else layers - 1
     q = jax.random.normal(ks[0], (B, Hq, D), dtype)
-    kp = jax.random.normal(ks[1], (P, page, Hkv * D), dtype)
-    vp = jax.random.normal(ks[2], (P, page, Hkv * D), dtype)
+    kp = jax.random.normal(ks[1], pool, dtype)
+    vp = jax.random.normal(ks[2], pool, dtype)
     bt = jax.random.randint(ks[3], (B, npages), 0, P)
-    maxlen = page * npages
-    ln = jax.random.randint(ks[4], (B,), 1, maxlen + 1).astype(jnp.int32)
-    o = paged_attention(q, kp, vp, bt, ln, interpret=True)
-    r = ref_paged_decode(q, kp, vp, bt, ln)
+    if lengths is None:
+        ln = jax.random.randint(ks[4], (B,), 1, page * npages + 1)
+    else:
+        ln = jnp.asarray(lengths)
+    ln = ln.astype(jnp.int32)
+    o = paged_decode_attention(q, kp, vp, bt, ln, layer=layer,
+                               interpret=True)
+    r = ref_paged_decode(q, kp, vp, bt, ln, layer=layer)
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(r, np.float32),
                                atol=_tol(dtype), rtol=_tol(dtype))

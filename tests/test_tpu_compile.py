@@ -82,14 +82,14 @@ def _nbytes(tree):
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
-def _kernel_call(name, cfg, sds):
+def _kernel_call(name, cfg, sds, npages=NPAGES):
     hq, d = cfg.n_heads, cfg.head_dim
     f = cfg.n_kv_heads * d
     i32, bf16 = jnp.int32, jnp.bfloat16
     pool = sds((cfg.n_layers, PAGES + 1, PAGE, f), bf16)
     if name == "paged_decode":
         return jax.jit(paged_decode_attention).lower(
-            sds((4, hq, d), bf16), pool, pool, sds((4, NPAGES), i32),
+            sds((4, hq, d), bf16), pool, pool, sds((4, npages), i32),
             sds((4,), i32), layer=sds((), i32))
     if name == "flash_prefill_paged":
         return jax.jit(flash_prefill_paged).lower(
@@ -109,6 +109,15 @@ def test_kernel_compiles_for_v5e(chip, name, model):
     """The main-path kernels' block shapes are legal on the chip at the
     config's head widths, and each lowers to a Mosaic custom call."""
     compiled = _kernel_call(name, get_config(model), chip).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_decode_compiles_for_v5e_at_8b_bucket(chip):
+    """The 8b cell's table bucket, 1,024 pages over four rows: the kernel's
+    double-buffered page blocks fit the chip's fast memory, and it lowers
+    to a Mosaic custom call."""
+    compiled = _kernel_call("paged_decode", get_config("qwen3-8b"), chip,
+                            npages=1024).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
