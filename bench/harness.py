@@ -127,10 +127,11 @@ class Run:
     pages_peak: int
     pool_pages: int
     compiles_window: int
-    shapes: object
+    shapes: object               # the family's shapes of the configuration
     peaks: dict | None
     trace: object = None         # TraceView in a traced run
     notes: dict = field(default_factory=dict)
+    family: object = None        # the configuration's bench/families module
 
 
 @dataclass
@@ -142,8 +143,11 @@ class TraceView:
 
 
 def _stats(engine) -> dict:
+    """Every counter of ``engine.stats()``, and five of them again under the
+    short names the readers use."""
     st = engine.stats
-    return {"computed": st.prefill_tokens_computed,
+    return {**engine.stats(),
+            "computed": st.prefill_tokens_computed,
             "reused": st.prefill_tokens_reused,
             "decode_tokens": st.decode_tokens,
             "decode_steps": st.decode_steps,
@@ -288,16 +292,17 @@ class Deployment:
         from repro.serving.engine import LocalDisaggEngine
         from repro.serving.registry import DecodeModelSpec
         self.conf, self.seed = conf, seed
-        self.shapes = bmodel.shapes(conf)
+        self.family = bmodel.family(conf)
+        self.shapes = self.family.shapes(conf)
         self.counter = CompileCounter()
         self.params_cls = SamplingParams
         dep = conf["deployment"]
         prefill = dep["prefill_module"]
         self.modules = sorted(m for m in dep["modules"] if m != prefill)
-        weights = {m: bmodel.init_module(conf, seed, m)
+        weights = {m: self.family.init_module(conf, seed, m)
                    for m in dep["modules"]}
         self.engine = LocalDisaggEngine(
-            bmodel.program_config(conf), weights[prefill],
+            self.family.program_config(conf), weights[prefill],
             num_pages=conf["pool_pages"], page_size=conf["page_size"],
             **conf["engine"])
         for m in self.modules:
@@ -397,7 +402,7 @@ class Deployment:
                   pages_peak=drv.pages_peak, pool_pages=self.conf["pool_pages"],
                   compiles_window=c_close - c_open, shapes=self.shapes,
                   peaks=peaks(dev.device_kind) if dev.platform == "tpu"
-                  else None, trace=view)
+                  else None, trace=view, family=self.family)
         run.notes.update(
             memory_peak_bytes=int(mem.get("peak_bytes_in_use", 0)),
             unsent=sum(len(self.modules) for d in drv.due if d[0] < t_close),

@@ -4,7 +4,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench import flops, tracefile
+from bench import tracefile
 
 #: a step program is told apart by the kernel that runs inside it
 STEP_KERNELS = {"decode": "paged_decode", "chunk": "flash_prefill_paged"}
@@ -75,14 +75,14 @@ def step_mfu(run, kind: str, model_flops: int) -> float | None:
 
 
 def decode_cost(run):
-    a, b = window_perf(run)
-    lengths = decode_lengths(run, a, b)
-    f, by = flops.paged_decode(run.shapes, lengths)
-    return f * run.shapes.layers, by * run.shapes.layers
+    """(operations, bytes) of every layer's ``paged_decode`` calls for the
+    tokens decoded in the traced window."""
+    return run.family.paged_decode(run.shapes,
+                                   decode_lengths(run, *window_perf(run)))
 
 
 def prefill_cost(run):
-    a, b = window_perf(run)
-    chunks = prefill_chunks(run, a, b)
-    f, by = flops.flash_prefill_paged(run.shapes, chunks)
-    return f * run.shapes.layers, by * run.shapes.layers
+    """(operations, bytes) of every layer's ``flash_prefill_paged`` calls
+    for the chunks prefilled in the traced window."""
+    return run.family.flash_prefill_paged(
+        run.shapes, prefill_chunks(run, *window_perf(run)))

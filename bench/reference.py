@@ -1,14 +1,18 @@
-"""The plain reference: Qwen3's forward pass in float32, layer by layer.
+"""The plain reference: the model's forward pass in float32, layer by layer.
 
 It imports nothing of the program. The weights are remade from the run seed
-by ``bench.model.init_module``, the same function that made the weights the
+by the family's ``init_module``, the same function that made the weights the
 program served. The prompt's keys and values come from the prefill (base)
 module; the served positions run through the decode module that served
 them, attending to the base module's prompt rows and to their own, which is
 PrefillShare's cache-conditioned decode. Each matrix product runs at
 ``precision=HIGHEST``.
 
-Departure from the published Qwen3, taken because the program takes it:
+A family's ``served_logits`` (``bench/families/<model_type>.py``) runs its
+own layers over the helpers here: matrix products, RMSNorm, rope, causal GQA
+attention, the embedding and the output head.
+
+Departure from the published models, taken because the program takes it:
 token embeddings enter scaled by ``sqrt(hidden_size)``.
 
 ``control=True`` is the same computation one precision step below the
@@ -24,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.model import Shapes, init_module, shapes
+from bench.model import family
 
 HIGHEST = jax.lax.Precision.HIGHEST
 QBLOCK = 128                 # query rows per attention block
@@ -83,40 +87,13 @@ def _attend(q, k, v, qpos, kpos):
     return o.reshape(S, hq * D)
 
 
-@partial(jax.jit, static_argnums=(0, 1))
-def _layer(s: Shapes, control: bool, w, x, qpos, kvalid, k_prev, v_prev,
-           kpos_prev):
-    """One decoder layer over rows x (S, d) at qpos; rows with kvalid False
-    are padding and give no keys. Returns (x, k, v) of these rows."""
-    S = x.shape[0]
-    a = w["attn"]
-    h = _rms(x, w["norm1"], s.eps)
-    q = _mm(h, a["wq"], control).reshape(S, s.hq, s.head_dim)
-    k = _mm(h, a["wk"], control).reshape(S, s.hkv, s.head_dim)
-    v = _mm(h, a["wv"], control).reshape(S, s.hkv, s.head_dim)
-    q = _rope(_rms(q, a["q_norm"], s.eps), qpos, s.rope_theta)
-    k = _rope(_rms(k, a["k_norm"], s.eps), qpos, s.rope_theta)
-    if control:
-        k, v = _q8(k, -1), _q8(v, -1)
-    kk = jnp.concatenate([k_prev, k])
-    vv = jnp.concatenate([v_prev, v])
-    kpos = jnp.concatenate([kpos_prev, jnp.where(kvalid, qpos, -1)])
-    x = x + _mm(_attend(q, kk, vv, qpos, kpos), a["wo"], control)
-    h = _rms(x, w["norm2"], s.eps)
-    m = w["mlp"]
-    x = x + _mm(jax.nn.silu(_mm(h, m["wi"], control)) * _mm(h, m["wu"],
-                                                              control),
-                m["wo"], control)
-    return x, k, v
-
-
 @partial(jax.jit, static_argnums=(0,))
-def _embed(s: Shapes, table, tokens):
+def _embed(s, table, tokens):
     return table[tokens].astype(jnp.float32) * jnp.float32(s.d ** 0.5)
 
 
 @partial(jax.jit, static_argnums=(0, 1))
-def _logits(s: Shapes, control: bool, x, norm, table):
+def _logits(s, control: bool, x, norm, table):
     x = _rms(x, norm, s.eps)
     t = table.astype(jnp.float32)
     if control:
@@ -130,40 +107,6 @@ def _layer_weights(params, layer):
 
 def _pad(n, m):
     return -(-n // m) * m
-
-
-def served_logits(s: Shapes, base, dec, prompt, served, first_token,
-                  control=False):
-    """Float32 logits (L, V) at each served position of one request: the
-    base module prefills ``prompt``; the decode module is fed
-    ``first_token`` and then each served token but the last."""
-    n, L = len(prompt), len(served)
-    Np, Lp = _pad(n, PROMPT_PAD), _pad(L, REPLY_PAD)
-    toks = np.zeros(Np, np.int32)
-    toks[:n] = prompt
-    pos = jnp.arange(Np, dtype=jnp.int32)
-    valid = pos < n
-    empty = jnp.zeros((0, s.hkv, s.head_dim), jnp.float32)
-    nokeys = jnp.zeros((0,), jnp.int32)
-    x = _embed(s, base["embed"], jnp.asarray(toks))
-    cache = []
-    for layer in range(s.layers):
-        x, k, v = _layer(s, control, _layer_weights(base, layer), x, pos,
-                         valid, empty, empty, nokeys)
-        cache.append((k, v))
-    del x
-    dtoks = np.zeros(Lp, np.int32)
-    dtoks[:L] = [first_token] + list(served[:-1])
-    dpos = n + jnp.arange(Lp, dtype=jnp.int32)
-    dvalid = jnp.arange(Lp) < L
-    kpos_prev = jnp.where(valid, pos, -1)
-    y = _embed(s, dec["embed"], jnp.asarray(dtoks))
-    for layer in range(s.layers):
-        k, v = cache[layer]
-        y, _, _ = _layer(s, control, _layer_weights(dec, layer), y, dpos,
-                         dvalid, k, v, kpos_prev)
-    table = dec["embed"] if s.tied else dec["unembed"]
-    return _logits(s, control, y, dec["final_norm"], table)[:L]
 
 
 @jax.jit
@@ -182,20 +125,22 @@ def logit_gaps(conf: dict, seed: int, sample: list, *, control=False):
     reference's best, and with ``control`` the gap of the token that the
     float8 computation puts first. Weights are remade one decode module at a
     time beside the base module."""
-    s = shapes(conf)
+    fam = family(conf)
+    s = fam.shapes(conf)
     prefill = conf["deployment"]["prefill_module"]
     out = [None] * len(sample)
     with jax.default_matmul_precision("highest"):
-        base = init_module(conf, seed, prefill)
+        base = fam.init_module(conf, seed, prefill)
         for module in sorted({r["module"] for r in sample}):
-            dec = init_module(conf, seed, module)
+            dec = fam.init_module(conf, seed, module)
             for i, r in enumerate(sample):
                 if r["module"] != module:
                     continue
                 args = (s, base, dec, r["prompt"], r["served"],
                         r["first_token"])
-                ref = served_logits(*args)
-                ctrl = served_logits(*args, control=True) if control else ref
+                ref = fam.served_logits(*args)
+                ctrl = (fam.served_logits(*args, control=True) if control
+                        else ref)
                 g, c = _gaps(ref, jnp.asarray(r["served"], jnp.int32), ctrl)
                 out[i] = {"gap": np.asarray(g),
                           "control_gap": np.asarray(c) if control else None}

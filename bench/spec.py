@@ -1,7 +1,16 @@
 """Find a cell's pieces by name: ``BENCHMARK.json`` names the cell, its
 configuration file and traffic mix; ``bench/traffic/<traffic>.json`` holds
 the mix and ``bench/metrics/<metric>.py`` the reader of each metric. Adding
-a cell, a mix or a metric adds files and entries; no file here changes."""
+a cell, a mix or a metric adds files and entries; no file here changes.
+
+A configuration is one file under ``bench/configs/`` whose ``model_type``
+names its family, ``bench/families/<model_type>.py`` (``bench.model.family``
+finds it). A configuration of a new architecture adds that family file too:
+its ``shapes``, ``program_config``, ``init_module``, ``served_logits`` and
+whole-model ``decode_step``, ``chunk_step``, ``paged_decode`` and
+``flash_prefill_paged`` counts, as ``bench/model.py`` lists them. A reader
+sees every counter of the engine's ``stats()`` in ``run.stats_open`` and
+``run.stats_close``."""
 from __future__ import annotations
 
 import importlib.util

@@ -58,15 +58,15 @@ def _nbytes(tree):
 
 def _setup(path, sds):
     conf = bmodel.load(path)
-    s = bmodel.shapes(conf)
-    dep = conf["deployment"]
-    params = jax.eval_shape(lambda: bmodel._init(
-        s, jax.random.PRNGKey(0), float(dep["embed_scale"]), 0.1, 0.02))
+    fam = bmodel.family(conf)
+    s = fam.shapes(conf)
+    params = jax.eval_shape(lambda: fam.init_module(
+        conf, 0, conf["deployment"]["prefill_module"]))
     params = jax.tree.map(lambda a: sds(a.shape, a.dtype), params)
     rows = (s.layers, conf["pool_pages"] + 1, conf["page_size"], s.kv_width)
     pool = {"groups": {"pos0": {"k": sds(rows, jnp.bfloat16),
                                 "v": sds(rows, jnp.bfloat16)}}, "tail": []}
-    return conf, bmodel.program_config(conf), params, pool
+    return conf, fam.program_config(conf), params, pool
 
 
 def _widest_table(conf):

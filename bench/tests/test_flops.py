@@ -1,10 +1,11 @@
 """The operation and byte counts against hand counts at one small shape."""
 from bench import flops
-from bench.model import Shapes
+from bench.model import family
 
+QWEN3 = family({"model_type": "qwen3"})
 # d=8, d_ff=16, 2 layers, 4 query heads and 2 kv heads of width 2, vocab 10
-S = Shapes(d=8, d_ff=16, layers=2, hq=4, hkv=2, head_dim=2, vocab=10,
-           tied=True, rope_theta=1e4, eps=1e-6)
+S = QWEN3.Shapes(d=8, d_ff=16, layers=2, hq=4, hkv=2, head_dim=2, vocab=10,
+                 tied=True, rope_theta=1e4, eps=1e-6)
 
 
 def test_paged_decode_by_hand():
@@ -31,8 +32,12 @@ def test_steps_by_hand():
     # times 2 for multiply-add: 2 * (64 + 64 + 64 + 384) = 1152
     layer = 1152
     head = 2 * 8 * 10
-    assert flops.decode_step(S, [3]) == 2 * layer + head + 2 * 3 * 32
-    assert flops.chunk_step(S, [(4, 3)]) == 2 * (3 * layer + 18 * 32)
+    assert QWEN3.decode_step(S, [3]) == 2 * layer + head + 2 * 3 * 32
+    assert QWEN3.chunk_step(S, [(4, 3)]) == 2 * (3 * layer + 18 * 32)
+    # the kernels' calls, once per layer
+    assert QWEN3.paged_decode(S, [3, 5]) == (2 * 8 * 32, 2 * (128 + 64))
+    assert QWEN3.flash_prefill_paged(S, [(4, 3), (0, 2)]) == (
+        2 * 21 * 32, 2 * (144 + 160))
 
 
 def test_shares_never_pass_the_peak():

@@ -157,6 +157,25 @@ def test_duplicate_prefill_share_over_the_window(before, after, want):
     assert reader("sched.duplicate_prefill_share")(Counted()) == want
 
 
+@pytest.mark.parametrize("before,after,want", [
+    ({"decode_table_pages": 100, "decode_live_pages": 40},
+     {"decode_table_pages": 500, "decode_live_pages": 240}, 50.0),
+    ({"decode_table_pages": 100, "decode_live_pages": 40},
+     {"decode_table_pages": 100, "decode_live_pages": 40}, None),
+    ({}, {}, None),
+])
+def test_decode_live_page_share_over_the_window(before, after, want):
+    """Δdecode_live_pages ÷ Δdecode_table_pages, on a hand-made run; a
+    window with no decode step, or a program without the counters, reads
+    nothing."""
+    from bench.harness import Run
+    run = Run(cell="synthetic", seconds=1.0, setup_s=1.0, t_open=0.0,
+              t_close=1.0, drain_end=1.0, window=[], requests=[],
+              stats_open=before, stats_close=after, pages_peak=0,
+              pool_pages=1, compiles_window=0, shapes=None, peaks=None)
+    assert reader("kv.decode_live_page_share")(run) == want
+
+
 def test_extract_keeps_program_spans_and_compile_events(tmp_path):
     """A trace taken here keeps the benchmark's and the program's spans and
     JAX's compile events, and no other host event."""
